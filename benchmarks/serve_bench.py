@@ -83,6 +83,7 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_serving_mesh
 from repro.launch.serve import deploy_model, serve_batch
 from repro.serving import (
@@ -820,6 +821,7 @@ def main():
         "--metrics-out", default="",
         help="export its aggregated step-phase metrics as JSON here")
     args = ap.parse_args()
+    setup_compile_cache()
 
     max_len = args.prompt_len + args.gen
     mixed_max_len = 2 * max_len  # room for near-arena-length prompts

@@ -17,8 +17,7 @@ engine, three `ServingConfig` knobs (`mesh=...`, `kv_shard=...`,
 
   --mesh N           ("data", "model") serving mesh, N devices on the
                      model axis; on a plain CPU host it forces N XLA
-                     host devices before jax initializes, so the whole
-                     path runs anywhere
+                     host devices before jax initializes
   --kv-shard         shard the KV arenas along kv heads over the mesh
                      model axis (GQA-aware; indivisible head counts
                      fall back to replication) — bit-exact with
@@ -27,16 +26,26 @@ engine, three `ServingConfig` knobs (`mesh=...`, `kv_shard=...`,
                      packing with the in-flight fused decode, blocking
                      only at token harvest (0 = synchronous)
 
-The second engine below runs that configuration in-process; with one
-visible device `make_serving_mesh` falls back to the 1-device host
-mesh and sharding degrades to replication — same code path, same
-tokens.
+The second engine below runs that configuration in-process on two
+devices.  On a CPU host this script asks XLA for two host devices
+before jax starts; `make_serving_mesh` raises where fewer exist.
 """
-import numpy as np
+import os
 
-from repro.launch.mesh import make_serving_mesh
-from repro.launch.serve import deploy_model
-from repro.serving import SchedulerConfig, ServingConfig, ServingEngine
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=2 "
+    + os.environ.get("XLA_FLAGS", "")
+)
+
+import numpy as np  # noqa: E402
+
+from repro.launch.mesh import make_serving_mesh  # noqa: E402
+from repro.launch.serve import deploy_model  # noqa: E402
+from repro.serving import (  # noqa: E402
+    SchedulerConfig,
+    ServingConfig,
+    ServingEngine,
+)
 
 lm, tables = deploy_model("granite_3_2b", reduced=True, max_seq=48)
 
@@ -68,7 +77,7 @@ print(f"{s['throughput_tok_s']:.1f} tok/s, "
       f"mean occupancy {s['mean_occupancy']:.2f}")
 
 # -- multi-device engine: sharded KV arena + async dispatch ----------
-mesh = make_serving_mesh(2)  # host-mesh fallback on a 1-device CPU
+mesh = make_serving_mesh(2)
 sharded = ServingEngine(lm, tables, ServingConfig(
     n_slots=3, max_len=48, paged=True, page_size=8,
     mesh=mesh, kv_shard=True, dispatch_depth=1,

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import pallas_on_platform
+
 
 def _kernel(x_ref, w_ref, b_ref, m_ref, s0_ref, o_ref, acc_ref, *,
             n_k: int, d: int, zp: int, qmin: int, qmax: int):
@@ -55,7 +57,6 @@ def _kernel(x_ref, w_ref, b_ref, m_ref, s0_ref, o_ref, acc_ref, *,
 def int8_matmul_requant_pallas(
     x, w, bias, mul, s0, *, d: int, zp: int = 0, qmin: int = -128,
     qmax: int = 127, bm: int = 128, bn: int = 128, bk: int = 128,
-    interpret: bool = True,
 ):
     """x (M, K) int8; w (K, N) int8; bias/mul/s0 (N,) int32 -> (M, N) int8.
 
@@ -69,18 +70,21 @@ def int8_matmul_requant_pallas(
     kern = functools.partial(
         _kernel, n_k=n_k, d=d, zp=zp, qmin=qmin, qmax=qmax
     )
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.int8),
-        grid=(M // bm, N // bn, n_k),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
-    )(x, w, bias, mul, s0)
+    def build(interpret):
+        return pl.pallas_call(
+            kern,
+            out_shape=jax.ShapeDtypeStruct((M, N), jnp.int8),
+            grid=(M // bm, N // bn, n_k),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+                pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+                pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+                pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+                pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+            interpret=interpret,
+        )
+
+    return pallas_on_platform(build, x, w, bias, mul, s0)

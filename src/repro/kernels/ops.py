@@ -29,12 +29,11 @@ def _pad_to(x, mult, axis):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("d", "zp", "qmin", "qmax", "bm", "bn", "bk", "interpret"),
+    static_argnames=("d", "zp", "qmin", "qmax", "bm", "bn", "bk"),
 )
 def int8_matmul_requant(x, w, bias, mul, s0, *, d: int, zp: int = 0,
                         qmin: int = -128, qmax: int = 127, bm: int = 128,
-                        bn: int = 128, bk: int = 128,
-                        interpret: bool = True):
+                        bn: int = 128, bk: int = 128):
     """x (..., K) int8 @ w (K, N) int8 -> (..., N) int8, requantized.
 
     Arbitrary leading dims; K/N padded to block multiples internally.
@@ -54,11 +53,11 @@ def int8_matmul_requant(x, w, bias, mul, s0, *, d: int, zp: int = 0,
 
     out = int8_matmul_requant_pallas(
         x2, w2, padv(bias), padv(mul, 1), padv(s0), d=d, zp=zp,
-        qmin=qmin, qmax=qmax, bm=bm, bn=bn, bk=bk, interpret=interpret)
+        qmin=qmin, qmax=qmax, bm=bm, bn=bn, bk=bk)
     return out[:M0, :N].reshape(*lead, N)
 
 
-def linear_rqt_kernel(s_x, ip: dict, rqt: dict, *, interpret: bool = True):
+def linear_rqt_kernel(s_x, ip: dict, rqt: dict):
     """Model-facing fusion: QLinear.apply_id + apply_rqt in one kernel.
 
     ip: {"w_q", "b_q"}; rqt: {"m","d","s0","lo","hi","zp"} (d scalar).
@@ -72,15 +71,14 @@ def linear_rqt_kernel(s_x, ip: dict, rqt: dict, *, interpret: bool = True):
     mul = jnp.broadcast_to(jnp.asarray(rqt["m"], jnp.int32), (N,))
     s0 = jnp.broadcast_to(jnp.asarray(rqt["s0"], jnp.int32), (N,))
     return int8_matmul_requant(
-        s_x, ip["w_q"], ip["b_q"], mul, s0, d=d, zp=zp,
-        interpret=interpret)
+        s_x, ip["w_q"], ip["b_q"], mul, s0, d=d, zp=zp)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("d", "zp", "qmin", "qmax", "bm", "interpret")
+    jax.jit, static_argnames=("d", "zp", "qmin", "qmax", "bm")
 )
 def requant(q, m, s0, lo, hi, *, d: int, zp: int = 0, qmin: int = -128,
-            qmax: int = 127, bm: int = 256, interpret: bool = True):
+            qmax: int = 127, bm: int = 256):
     """q (..., N) int32 -> (..., N) int8 via the VPU kernel."""
     lead = q.shape[:-1]
     N = q.shape[-1]
@@ -97,14 +95,13 @@ def requant(q, m, s0, lo, hi, *, d: int, zp: int = 0, qmin: int = -128,
         qmin=qmin,
         qmax=qmax,
         bm=bm,
-        interpret=interpret,
     )
     return out[:M0].reshape(*lead, N)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "score_scale", "eps_ctx", "causal", "q_offset", "bq", "bkv",
-    "n_rep", "interpret"))
+    "n_rep"))
 def quant_flash_attention(
     q,
     k,
@@ -117,7 +114,6 @@ def quant_flash_attention(
     n_rep: int = 1,
     bq: int = 128,
     bkv: int = 128,
-    interpret: bool = True,
 ):
     """GQA wrapper.  q (B, H, S_q, hd); k/v (B, K, S_kv, hd) int8;
     n_rep = H // K.  Returns (B, H, S_q, hd) int8 ctx image."""
@@ -133,6 +129,5 @@ def quant_flash_attention(
     qf, Sq0 = _pad_to(qf, bq, 1)
     out = quant_flash_attention_pallas(
         qf, kf, vf, score_scale=score_scale, eps_ctx=eps_ctx,
-        causal=causal, q_offset=q_offset, bq=bq, bkv=bkv,
-        interpret=interpret)
+        causal=causal, q_offset=q_offset, bq=bq, bkv=bkv)
     return out[:, :Sq0].reshape(B, H, S_q, hd)

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_on_platform
+
 NEG_INF = -1e9
 
 
@@ -44,12 +46,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, score_scale: float,
 
     def body(j, carry):
         m_old, l_old, acc = carry
-        # pl.ds(0, 1) instead of an int 0: interpret-mode discharge
-        # rejects scalar int indices (AttributeError on .shape)
-        k_blk = pl.load(
-            k_ref, (pl.ds(0, 1), pl.ds(j * bkv, bkv), slice(None)))[0]
-        v_blk = pl.load(
-            v_ref, (pl.ds(0, 1), pl.ds(j * bkv, bkv), slice(None)))[0]
+        k_blk = k_ref[0, pl.ds(j * bkv, bkv), :]
+        v_blk = v_ref[0, pl.ds(j * bkv, bkv), :]
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.int32)          # (bq, bkv)
@@ -84,7 +82,6 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, score_scale: float,
 def quant_flash_attention_pallas(
     q, k, v, *, score_scale: float, eps_ctx: float, causal: bool = True,
     q_offset: int = 0, bq: int = 128, bkv: int = 128,
-    interpret: bool = True,
 ):
     """q (BH, S_q, hd) int8; k/v (BH, S_kv, hd) int8 -> (BH, S_q, hd) int8.
 
@@ -98,15 +95,18 @@ def quant_flash_attention_pallas(
         _kernel, score_scale=float(score_scale),
         inv_eps_ctx=float(1.0 / eps_ctx), bkv=bkv, kv_len=S_kv,
         q_offset=q_offset, causal=causal, bq=bq)
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct((BH, S_q, hd), jnp.int8),
-        grid=(BH, S_q // bq),
-        in_specs=[
-            pl.BlockSpec((1, bq, hd), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, S_kv, hd), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, S_kv, hd), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, hd), lambda b, i: (b, i, 0)),
-        interpret=interpret,
-    )(q, k, v)
+    def build(interpret):
+        return pl.pallas_call(
+            kern,
+            out_shape=jax.ShapeDtypeStruct((BH, S_q, hd), jnp.int8),
+            grid=(BH, S_q // bq),
+            in_specs=[
+                pl.BlockSpec((1, bq, hd), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, S_kv, hd), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((1, S_kv, hd), lambda b, i: (b, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, hd), lambda b, i: (b, i, 0)),
+            interpret=interpret,
+        )
+
+    return pallas_on_platform(build, q, k, v)

@@ -120,17 +120,18 @@ def paged_attention_ref(
     same-shaped per-row sums as the kernel, so the mirror is bit-exact
     against it (tolerance 0 in tests).
 
-    q (B, H, S, hd) int8; pools (n_pages + 1, K, ps, hd) int8;
+    q (B, H, S, hd) int8; position-minor pools (n_pages + 1, K, hd, ps)
+    int8;
     table (B, pps) int32; pos (B,) int32 position of query row 0.
     -> (B, H, S, hd) int32 accumulator (eps_p * eps_v units; ctx_rqt
     applied by the caller).
 
     With `k_rq`/`v_rq` (6, K) int32 the pools are int4-packed
-    (ps, hd/2) and every page read goes through `kv4_unpack_page_ref`
+    (hd/2, ps) and every page read goes through `kv4_unpack_page_ref`
     first — the (S, T) mirror of the packed kernel mode.
     """
     B, H, S, hd = q.shape
-    _, K, ps, _ = k_pool.shape
+    _, K, _, ps = k_pool.shape
     pps = table.shape[1]
     assert H == K * group, (H, K, group)
 
@@ -139,7 +140,7 @@ def paged_attention_ref(
         blocks = []
         for j in range(pps):
             page = table[b, j]
-            k_page = k_pool[page, h // group]          # (ps, hd)
+            k_page = k_pool[page, h // group].T        # (ps, hd)
             if k_rq is not None:
                 k_page = kv4_unpack_page_ref(k_page, k_rq, h // group)
             s = jax.lax.dot_general(
@@ -157,7 +158,7 @@ def paged_attention_ref(
         acc = jnp.zeros((S, hd), jnp.int32)
         for j in range(pps):
             page = table[b, j]
-            v_page = v_pool[page, h // group]
+            v_page = v_pool[page, h // group].T
             if v_rq is not None:
                 v_page = kv4_unpack_page_ref(v_page, v_rq, h // group)
             acc = acc + jax.lax.dot_general(

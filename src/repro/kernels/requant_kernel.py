@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_on_platform
+
 
 def _kernel(q_ref, m_ref, s0_ref, lo_ref, hi_ref, o_ref, *, d: int,
             zp: int, qmin: int, qmax: int):
@@ -38,23 +40,25 @@ def requant_pallas(
     qmin: int = -128,
     qmax: int = 127,
     bm: int = 256,
-    interpret: bool = True,
 ):
     """q (M, N) int32; m/s0/lo/hi (N,) int32 -> (M, N) int8."""
     M, N = q.shape
     assert M % bm == 0, (M, bm)
     kern = functools.partial(_kernel, d=d, zp=zp, qmin=qmin, qmax=qmax)
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.int8),
-        grid=(M // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, N), lambda i: (i, 0)),
-            pl.BlockSpec((N,), lambda i: (0,)),
-            pl.BlockSpec((N,), lambda i: (0,)),
-            pl.BlockSpec((N,), lambda i: (0,)),
-            pl.BlockSpec((N,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
-        interpret=interpret,
-    )(q, m, s0, lo, hi)
+    def build(interpret):
+        return pl.pallas_call(
+            kern,
+            out_shape=jax.ShapeDtypeStruct((M, N), jnp.int8),
+            grid=(M // bm,),
+            in_specs=[
+                pl.BlockSpec((bm, N), lambda i: (i, 0)),
+                pl.BlockSpec((N,), lambda i: (0,)),
+                pl.BlockSpec((N,), lambda i: (0,)),
+                pl.BlockSpec((N,), lambda i: (0,)),
+                pl.BlockSpec((N,), lambda i: (0,)),
+            ],
+            out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
+            interpret=interpret,
+        )
+
+    return pallas_on_platform(build, q, m, s0, lo, hi)

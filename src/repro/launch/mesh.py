@@ -20,41 +20,37 @@ from __future__ import annotations
 import jax
 
 
-def _axis_kwargs(n_axes: int) -> dict:
-    """jax.make_mesh kwargs, tolerant of jax versions without AxisType."""
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is None:
-        return {}
-    return {"axis_types": (at.Auto,) * n_axes}
+def _auto(n_axes: int) -> dict:
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, **_auto(len(axes)))
 
 
 def make_host_mesh():
     """1-device mesh with the same axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"), **_axis_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"), **_auto(2))
 
 
 def make_serving_mesh(n_model: int = 0, *, n_data: int = 1):
     """("data", "model") mesh for the multi-device serving engine.
 
     `n_model` is the KV-shard width (0 = every device not claimed by
-    `n_data`).  Host-mesh fallback: when the platform exposes fewer
-    devices than requested — a plain CPU run without the forced
-    host-platform device count — this degrades to the 1-device host
-    mesh instead of failing, so the same serving entry point runs
-    everywhere and sharding simply becomes replication.
+    `n_data`).  Raises when the platform exposes fewer devices than the
+    mesh needs: a CPU run forces its host devices itself
+    (`--xla_force_host_platform_device_count`) before jax starts.
     """
     n_dev = jax.device_count()
     n_data = max(1, n_data)
     if n_model <= 0:
         n_model = max(1, n_dev // n_data)
     if n_data * n_model > n_dev:
-        return make_host_mesh()
-    return jax.make_mesh(
-        (n_data, n_model), ("data", "model"), **_axis_kwargs(2)
-    )
+        raise ValueError(
+            f"serving mesh ({n_data}, {n_model}) needs "
+            f"{n_data * n_model} devices, {jax.default_backend()} has "
+            f"{n_dev}"
+        )
+    return jax.make_mesh((n_data, n_model), ("data", "model"), **_auto(2))

@@ -24,11 +24,14 @@ Multi-device serving (DESIGN.md §Serving ¶Multi-device): `--mesh N`
 builds a ("data", "model") serving mesh with N devices on the model
 axis, `--kv-shard` shards the KV arena along kv heads over it, and
 `--dispatch-depth 1` overlaps host scheduling with the in-flight
-device step.  On a single-CPU host `--mesh N` forces N XLA host
-devices before jax initializes (the launch/dryrun.py trick), so the
-whole multi-device path runs anywhere; if the platform still exposes
-fewer devices than asked, make_serving_mesh falls back to the 1-device
-host mesh and sharding degrades to replication.
+device step.  On a CPU host `--mesh N` forces N XLA host devices
+before jax initializes (the launch/dryrun.py trick); a platform that
+still exposes fewer devices than asked makes make_serving_mesh raise.
+
+Kernels compile with Mosaic on a TPU and run in the Pallas interpreter
+on the CPU (kernels/platform.py).  On a TPU the paged arena's page size
+must be a multiple of 128 (the default); `chip_smoke.py` at the repo
+root serves the full-width model on one chip.
 
 CPU-scale example:
   PYTHONPATH=src python -m repro.launch.serve --arch granite_3_2b \
@@ -80,6 +83,7 @@ import numpy as np  # noqa: E402
 from repro.configs.base import get_config  # noqa: E402
 from repro.core.rep import Rep  # noqa: E402
 from repro.data.synthetic import SyntheticConfig, SyntheticStream  # noqa: E402
+from repro.launch.compile_cache import setup_compile_cache  # noqa: E402
 from repro.models.lm import DecoderLM  # noqa: E402
 from repro.serving import (  # noqa: E402
     Request,
@@ -97,6 +101,11 @@ from repro.serving import (  # noqa: E402
 def deploy_model(
     arch: str, *, reduced: bool, max_seq: int, calib_batch: int = 4
 ):
+    """Random float params (seed 0) -> calibrate on a synthetic batch on
+    the default device -> integer tables built on the host and placed
+    on the default device.  The float params leave the device before
+    the tables arrive: at granite_3_2b's full width they are 10.5 GB
+    of float32 against 2.6 GB of tables, and one v5e holds 16 GiB."""
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
@@ -108,7 +117,9 @@ def deploy_model(
         global_batch=calib_batch))
     sample = jnp.asarray(stream.batch(0))[:, :-1]
     calib = lm.calibrate(p, sample)
+    p = jax.tree.map(np.asarray, p)  # drops the device copy
     tables = lm.deploy(p, calib)
+    del p
     tables = jax.tree.map(
         jnp.asarray, tables, is_leaf=lambda x: isinstance(x, np.ndarray))
     return lm, tables
@@ -169,7 +180,12 @@ def main():
         help="paged KV arena (page budgets instead of "
         "worst-case slot rows)",
     )
-    ap.add_argument("--page-size", type=int, default=16)
+    ap.add_argument(
+        "--page-size",
+        type=int,
+        default=128,
+        help="positions per KV page (a multiple of 128 on a TPU)",
+    )
     ap.add_argument(
         "--kv-bits",
         type=int,
@@ -289,6 +305,7 @@ def main():
         "TraceAnnotation (enables telemetry)",
     )
     args = ap.parse_args()
+    setup_compile_cache()
 
     mesh = None
     if args.mesh:

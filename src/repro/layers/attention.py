@@ -42,9 +42,11 @@ chunk completed.
 
 Paged KV (serving.cache.PagedArena): a decode cache dict may carry a
 per-slot page "table" (B, pages_per_slot) next to its pooled "k"/"v"
-leaves (n_pages + 1, K, page_size, hd).  The new column(s) are
-scattered into the pages holding each row's `pos`; ID attention —
-single-token decode AND multi-token chunked-prefill — then runs the
+leaves (n_pages + 1, K, hd, page_size) — position-minor, the layout
+the TPU kernel copies page by page (kernels/paged_attention.py).  The
+new column(s) are scattered into the pages holding each row's `pos`;
+ID attention — single-token decode AND multi-token chunked-prefill —
+then runs the
 fused paged-attention kernel straight over the pools
 (kernels/paged_attention.py — bit-exact with the unfused math, see
 its module doc) unless `variants paged_decode="gather"` selects the
@@ -339,7 +341,7 @@ class QAttention:
                 # KV): a pool whose trailing axis is hd/2 stores two
                 # nibbles per cell — thread the per-head pack/unpack
                 # requant images through the write and the read
-                kv4 = t["kv4"] if cache["k"].shape[-1] != hd else None
+                kv4 = t["kv4"] if cache["k"].shape[-2] != hd else None
                 if (variants.get("paged_decode") == "kernel"
                         and variants.get("attn_softmax") != "int"):
                     # fused paged attention (S == 1 decode, S > 1
@@ -548,7 +550,7 @@ def _positions(S: int, pos):
 def _paged_kv_view(pool, table):
     """Gather the logical (B, K, T, hd) KV view through a page table.
 
-    pool: (n_pages + 1, K, page_size, hd); table: (B, pages_per_slot)
+    pool: (n_pages + 1, K, hd, page_size); table: (B, pages_per_slot)
     int32 physical page ids (PAGE_NULL entries point at the trash page
     and surface garbage that per-slot masking hides — every position a
     request has written lives in a page its table row owns).
@@ -556,8 +558,8 @@ def _paged_kv_view(pool, table):
     """
     B, pps = table.shape
     x = jnp.take(pool, table.reshape(-1), axis=0)
-    x = x.reshape((B, pps) + pool.shape[1:])
-    x = jnp.moveaxis(x, 1, 2)                     # (B, K, pps, ps, hd)
+    x = x.reshape((B, pps) + pool.shape[1:])      # (B, pps, K, hd, ps)
+    x = jnp.transpose(x, (0, 2, 1, 4, 3))         # (B, K, pps, ps, hd)
     return x.reshape(x.shape[0], x.shape[1], -1, x.shape[-1])
 
 
@@ -581,7 +583,7 @@ def _paged_column_write(pool, new, pos, table):
     kv-head-sharded pools inherit sharing for free (page ids are
     shard-invariant; only head columns split).
     """
-    ps = pool.shape[2]
+    ps = pool.shape[3]
     B, _, S, _ = new.shape
     pps = table.shape[1]
     positions = pos[:, None] + jnp.arange(S)          # (B, S)
@@ -592,7 +594,7 @@ def _paged_column_write(pool, new, pos, table):
     off = positions % ps
     new_f = jnp.moveaxis(new, 2, 1).reshape((B * S,) + new.shape[1:2]
                                             + new.shape[3:])
-    return pool.at[page.reshape(-1), :, off.reshape(-1), :].set(
+    return pool.at[page.reshape(-1), :, :, off.reshape(-1)].set(
         new_f.astype(pool.dtype))
 
 

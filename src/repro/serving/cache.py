@@ -597,7 +597,7 @@ class PagedArena:
         lm,
         n_slots: int,
         max_len: int,
-        page_size: int = 16,
+        page_size: int = 128,
         n_pages: int = 64,
         *,
         mesh=None,
@@ -631,10 +631,13 @@ class PagedArena:
         ) = _probe_axes(lm, max_len)
 
         # Pool: paged leaves swap (B, max_len) for (n_pages + 1,
-        # page_size); per-slot leaves (no sequence axis) keep B=n_slots.
+        # page_size) and move the page_size axis last — position-minor
+        # pages (..., K, hd, page_size), the layout the TPU kernel
+        # copies whole (kernels/paged_attention.py ¶Memory scope);
+        # per-slot leaves (no sequence axis) keep B=n_slots.
         # kv_bits=4 (DESIGN.md §Serving ¶Sub-8-bit KV) additionally
-        # halves each KV leaf's trailing head_dim — two int4 nibbles
-        # per int8 cell, packed along hd so every page cell stays
+        # halves each KV leaf's head_dim — two int4 nibbles per int8
+        # cell, packed along hd so every page cell stays
         # position-complete and the page/table math is untouched.
         leaves = []
         for leaf, b_ax, s_ax in zip(
@@ -659,6 +662,7 @@ class PagedArena:
                             f"{shape[last]}"
                         )
                     shape[last] //= 2
+                shape.append(shape.pop(s_ax))
             leaves.append(jnp.zeros(shape, leaf.dtype))
         self.caches = jax.tree.unflatten(self._treedef, leaves)
         # pool leaves swap (B, T) for (pages, page_size) but keep the
@@ -712,7 +716,8 @@ class PagedArena:
                     + (self.pages_per_slot, self.page_size)
                     + shp[sa + 1 :]
                 )
-                z = jnp.moveaxis(z, sa, b_ax)
+                # position-minor pages, then the page axis at b_ax
+                z = jnp.moveaxis(jnp.moveaxis(z, sa + 1, -1), sa, b_ax)
                 idx = (slice(None),) * b_ax + (table_row,)
                 # unallocated logical blocks land on the trash page
                 out.append(x.at[idx].set(z))
@@ -1246,8 +1251,8 @@ class PagedArena:
         extra compilation.
 
         This IS the fused paged-attention kernel's layout contract
-        (kernels/paged_attention.py): int8 pools
-        (n_pages + 1, K, page_size, hd) with physical page 0 reserved
+        (kernels/paged_attention.py): position-minor int8 pools
+        (n_pages + 1, K, hd, page_size) with physical page 0 reserved
         as the PAGE_NULL trash page, an int32 (n_slots,
         pages_per_slot) table whose stale/unallocated entries point at
         PAGE_NULL, and the engine's int32 per-slot position vector

@@ -48,7 +48,8 @@ class ServingConfig:
     max_len: int = 256
     # paged arena (DESIGN.md §Serving ¶Paged KV)
     paged: bool = False
-    page_size: int = 16
+    # a multiple of 128 on a TPU (kernels/paged_attention.TPU_PAGE_ALIGN)
+    page_size: int = 128
     n_pages: Optional[int] = None  # None: SlotArena-equivalent positions
     # paged decode variant: None -> the fused kernel iff paged
     paged_kernel: Optional[bool] = None

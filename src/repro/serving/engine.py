@@ -118,6 +118,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.rep import Rep
+from repro.kernels.paged_attention import TPU_PAGE_ALIGN
 from repro.layers.attention import INACTIVE_POS
 from repro.serving.cache import (
     Arena,
@@ -270,6 +271,25 @@ class ServingEngine:
                 tables, jax.tree.map(lambda _: repl, tables)
             )
         self.tables = tables
+        # paged attention path: the fused paged-attention kernel by
+        # default (kernels/paged_attention.py — K/V stream page by page
+        # through the table, no dense logical gather), or the
+        # write-then-gather jnp oracle when paged_kernel=False.  The
+        # variant is pinned at trace time, so each compiled dispatch
+        # bakes the chosen path in — for BOTH the decode and the
+        # unified (S-wide) dispatch.
+        self.paged_kernel = cfg.paged if cfg.paged_kernel is None else (
+            bool(cfg.paged_kernel) and cfg.paged
+        )
+        device = (
+            mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+        )
+        if (self.paged_kernel and device.platform == "tpu"
+                and cfg.page_size % TPU_PAGE_ALIGN):
+            raise ValueError(
+                f"page_size {cfg.page_size}: the TPU paged-attention "
+                f"kernel needs a multiple of {TPU_PAGE_ALIGN}"
+            )
         self.arena: Arena = make_arena(lm, cfg)
         assert_integer_caches(
             self.arena.caches,
@@ -287,17 +307,6 @@ class ServingEngine:
         # the pending queue for re-admission (¶Preemption bit-exactness)
         self._resume: Dict[int, ResumeState] = {}
         self._next_id = 0
-
-        # paged attention path: the fused paged-attention kernel by
-        # default (kernels/paged_attention.py — K/V stream page by page
-        # through the table, no dense logical gather), or the
-        # write-then-gather jnp oracle when paged_kernel=False.  The
-        # variant is pinned at trace time, so each compiled dispatch
-        # bakes the chosen path in — for BOTH the decode and the
-        # unified (S-wide) dispatch.
-        self.paged_kernel = cfg.paged if cfg.paged_kernel is None else (
-            bool(cfg.paged_kernel) and cfg.paged
-        )
 
         def _decode_step(t, token, caches, pos):
             from repro.launch import variants

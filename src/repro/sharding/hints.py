@@ -63,7 +63,7 @@ _KINDS = {
     "act_bs_only": [(0, None)],  # residual without seq sharding (MoE
                                  # blocks: avoids the SP<->EP reshard)
     "kv_heads": [(None, 1)],    # serving KV cache (B, K, T, hd) or page
-                                # pool (P, K, ps, hd): kv heads on model,
+                                # pool (P, K, hd, ps): kv heads on model,
                                 # batch/pages replicated — keeps the
                                 # arena's layout pinned through the
                                 # per-slot write and the paged gather

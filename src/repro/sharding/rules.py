@@ -184,14 +184,15 @@ def kv_head_axis(batch_axis: int, seq_axis) -> Optional[int]:
 
     Every attention cache layout the model zoo produces keeps the head
     axis immediately BEFORE the sequence axis — (..., B, K, T, hd) for
-    contiguous slot rows and (..., n_pages + 1, K, page_size, hd) for
-    paged pools, where the arena's structural probe reports the same
-    (batch_axis, seq_axis) pair for both.  Leaves with no sequence axis
-    (SSM recurrent state) have no head axis to shard.
+    contiguous slot rows.  Paged pools (..., n_pages + 1, K, hd,
+    page_size) move the page_size axis last, but the arena's structural
+    probe reports the contiguous template's (batch_axis, seq_axis)
+    pair for them, so the same rule lands on K.  Leaves with no
+    sequence axis (SSM recurrent state) have no head axis to shard.
 
     Int4-packed pools (DESIGN.md §Serving ¶Sub-8-bit KV) only halve
-    the trailing hd axis — the head axis stays just before the
-    sequence axis, so packed pools shard exactly like int8 ones: only
+    the hd axis — the head axis stays where it was, so packed pools
+    shard exactly like int8 ones: only
     the kv-head axis splits, nibble pairs never straddle a shard.
     """
     if seq_axis is None:

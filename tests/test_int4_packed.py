@@ -101,9 +101,14 @@ def test_packed_column_write_matches_pack_of_unpacked(seed):
     rng = np.random.default_rng(seed)
     n_pages, K, ps, hd = 5, 2, 4, 8
     B, S = 3, int(rng.integers(1, 6))
-    pool8 = rng.integers(-8, 8, size=(n_pages + 1, K, ps, hd))
+    # position-minor pools (n_pages + 1, K, hd, ps): nibbles pack along hd
+    pool8 = rng.integers(-8, 8, size=(n_pages + 1, K, hd, ps))
     pool8 = jnp.asarray(pool8.astype(np.int8))
-    pool4 = pack_int4(pool8)
+
+    def pack_pool(p):
+        return jnp.swapaxes(pack_int4(jnp.swapaxes(p, -1, -2)), -1, -2)
+
+    pool4 = pack_pool(pool8)
     table = jnp.asarray(
         rng.integers(0, n_pages + 1, size=(B, 3)).astype(np.int32))
     pos = rng.integers(0, 3 * ps, size=(B,)).astype(np.int32)
@@ -114,7 +119,7 @@ def test_packed_column_write_matches_pack_of_unpacked(seed):
     new = jnp.asarray(new)
     out8 = _paged_column_write(pool8, new, pos, table)
     out4 = _paged_column_write(pool4, pack_int4(new), pos, table)
-    assert np.array_equal(np.asarray(out4), np.asarray(pack_int4(out8)))
+    assert np.array_equal(np.asarray(out4), np.asarray(pack_pool(out8)))
 
 
 # ---------------------------------------------------------------------
@@ -175,9 +180,9 @@ def test_packed_kernel_matches_ref(s_q, group):
     q = jnp.asarray(
         rng.integers(-127, 128, size=(B, H, s_q, hd)).astype(np.int8))
     k_pool = jnp.asarray(rng.integers(
-        -128, 128, size=(n_pages + 1, K, ps, hd // 2)).astype(np.int8))
+        -128, 128, size=(n_pages + 1, K, hd // 2, ps)).astype(np.int8))
     v_pool = jnp.asarray(rng.integers(
-        -128, 128, size=(n_pages + 1, K, ps, hd // 2)).astype(np.int8))
+        -128, 128, size=(n_pages + 1, K, hd // 2, ps)).astype(np.int8))
     table = jnp.asarray(
         rng.integers(0, n_pages + 1, size=(B, pps)).astype(np.int32))
     pos = jnp.asarray(np.array([0, 5, INACTIVE_POS], np.int32))
@@ -269,8 +274,8 @@ def test_arena_packed_geometry(deployed):
         for x8, x4 in zip(l8, l4) if x8.shape != x4.shape
     ]
     assert halved, "kv_bits=4 arena halved no leaf"
-    for s8, s4 in halved:
-        assert s4 == s8[:-1] + (s8[-1] // 2,)
+    for s8, s4 in halved:  # position-minor pools: (..., hd, ps)
+        assert s4 == s8[:-2] + (s8[-2] // 2, s8[-1])
 
 
 def test_kv_bits_validation(deployed):

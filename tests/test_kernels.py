@@ -1,4 +1,5 @@
-"""Pallas kernel validation: interpret-mode execution vs pure-jnp oracles.
+"""Pallas kernel validation vs pure-jnp oracles.  On the CPU every kernel
+runs in the Pallas interpreter (kernels/platform.py picks the mode).
 
 Integer kernels demand EXACT equality (tolerance 0) against ref.py;
 shape/dtype sweeps cover the model's real call sites (head dims 64..192,

@@ -46,11 +46,12 @@ def deployed():
 
 
 def _rand_pools(rng, n_pages, K, ps, hd):
+    """Position-minor pools (n_pages + 1, K, hd, ps), the arena layout."""
     kp = jnp.asarray(
-        rng.integers(-127, 128, size=(n_pages + 1, K, ps, hd)), jnp.int8
+        rng.integers(-127, 128, size=(n_pages + 1, K, hd, ps)), jnp.int8
     )
     vp = jnp.asarray(
-        rng.integers(-127, 128, size=(n_pages + 1, K, ps, hd)), jnp.int8
+        rng.integers(-127, 128, size=(n_pages + 1, K, hd, ps)), jnp.int8
     )
     return kp, vp
 
@@ -420,3 +421,23 @@ def test_no_dense_gather_engine_wide_mixed(deployed):
     assert gather_calls, "gather oracle path no longer gathers"
     assert kernel_toks == gather_toks
     assert kernel_toks == async_toks
+
+
+def test_engine_refuses_unaligned_pages_on_tpu(deployed, monkeypatch):
+    """On a TPU the kernel addresses logits at lane offset j * page_size,
+    so the engine refuses a page size that is not a multiple of 128 —
+    it never switches to the gather path instead.  The gather path
+    itself compiles at any page size."""
+    from repro.kernels.paged_attention import TPU_PAGE_ALIGN
+
+    class Chip:
+        platform = "tpu"
+
+    lm, tables = deployed
+    monkeypatch.setattr(jax, "devices", lambda *a: [Chip()])
+    with pytest.raises(ValueError, match=f"multiple of {TPU_PAGE_ALIGN}"):
+        make_engine(lm, tables, n_slots=2, max_len=MAX_LEN, paged=True,
+                    page_size=16)
+    monkeypatch.undo()
+    make_engine(lm, tables, n_slots=2, max_len=MAX_LEN, paged=True,
+                page_size=16)  # the CPU interpreter takes any page size

@@ -266,10 +266,10 @@ def test_paged_write_gather_matches_contiguous():
     # slot b owns pages [1 + b*pps, ...); rebuild the pool from dense
     table = jnp.asarray(
         1 + np.arange(B * pps).reshape(B, pps), jnp.int32)
-    pool = jnp.zeros((B * pps + 1, K, ps, hd), jnp.int8)
+    pool = jnp.zeros((B * pps + 1, K, hd, ps), jnp.int8)
     pool = pool.at[table.reshape(-1)].set(
-        jnp.moveaxis(dense.reshape(B, K, pps, ps, hd), 2, 1)
-        .reshape(B * pps, K, ps, hd))
+        jnp.transpose(dense.reshape(B, K, pps, ps, hd), (0, 2, 1, 4, 3))
+        .reshape(B * pps, K, hd, ps))
     np.testing.assert_array_equal(
         np.asarray(_paged_kv_view(pool, table)), np.asarray(dense))
 

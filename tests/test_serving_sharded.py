@@ -125,6 +125,16 @@ def test_indivisible_heads_degrade_to_replication(mesh):
     assert all(ax is None for ax in spec)  # fully replicated
 
 
+def test_serving_mesh_raises_on_too_few_devices():
+    """No silent 1-device fallback: a mesh wider than the platform's
+    device count is an error naming both counts."""
+    n = jax.device_count()
+    with pytest.raises(ValueError, match=f"needs {n + 1} devices"):
+        make_serving_mesh(n + 1)
+    with pytest.raises(ValueError, match="needs"):
+        make_serving_mesh(2, n_data=n)
+
+
 # ---------------------------------------------------------------------
 # sharded == single-device, token for token (tentpole acceptance)
 # ---------------------------------------------------------------------
