@@ -299,10 +299,11 @@ def main():
         "(enables telemetry)",
     )
     ap.add_argument(
-        "--profile-annotations",
-        action="store_true",
-        help="wrap device dispatches in jax.profiler."
-        "TraceAnnotation (enables telemetry)",
+        "--profile-dir",
+        default="",
+        help="write a jax profiler trace of the serve here, with the "
+        "engine's steps, phases and dispatches annotated in it "
+        "(enables telemetry)",
     )
     args = ap.parse_args()
     setup_compile_cache()
@@ -318,8 +319,8 @@ def main():
     max_len = args.max_len or (args.prompt_len + args.gen)
     lm, tables = deploy_model(args.arch, reduced=args.reduced, max_seq=max_len)
     tel = None
-    if args.trace_out or args.metrics_out or args.profile_annotations:
-        tel = Telemetry(profile_annotations=args.profile_annotations)
+    if args.trace_out or args.metrics_out or args.profile_dir:
+        tel = Telemetry(profile_annotations=bool(args.profile_dir))
     engine = ServingEngine(lm, tables, ServingConfig(
         n_slots=args.slots, max_len=max_len,
         paged=args.paged, page_size=args.page_size,
@@ -372,6 +373,8 @@ def main():
                 priority=i % 2 if args.policy == "priority" else 0,
             ))
     open_loop = None
+    if args.profile_dir:
+        jax.profiler.start_trace(args.profile_dir)
     if args.arrival_rate > 0:
         open_loop = run_open_loop(
             engine, requests,
@@ -384,6 +387,8 @@ def main():
             engine.submit(req)
             engine.step()  # arrivals interleave with decoding
         completions = engine.run_until_drained()
+    if args.profile_dir:
+        jax.profiler.stop_trace()
     s = engine.stats()
     if mesh is not None:
         print(
@@ -453,6 +458,8 @@ def main():
             f"-> {c.n_generated} toks [{c.finish_reason}] "
             f"{np.asarray(c.tokens)[:8]}"
         )
+    if args.profile_dir:
+        print(f"  profiler trace -> {args.profile_dir}")
     if tel is not None:
         if args.trace_out:
             tel.export_trace(args.trace_out)

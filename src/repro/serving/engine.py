@@ -93,8 +93,9 @@ Telemetry (`telemetry=`, DESIGN.md §Observability): the engine threads
 an off-by-default, bit-neutral observability sink through every
 lifecycle transition (typed trace events), every step phase (spans:
 admission / plan_chunks / unified_dispatch / decode_dispatch /
-harvest), and every jitted dispatch (compile-cache
-hit/miss accounting + optional jax.profiler.TraceAnnotation).  All
+harvest, with advance inside harvest), and every jitted dispatch
+(compile-cache hit/miss accounting); with profile_annotations the
+step, its phases and its dispatches land in the profiler trace.  All
 hooks read host state only — no device values, no extra dispatches —
 so enabling telemetry cannot change a single token (pinned by
 tests/test_telemetry.py).  Independent of telemetry, per-token emit
@@ -833,36 +834,38 @@ class ServingEngine:
         return _InFlightDecode(tokens=nxt, slots=list(self.active))
 
     def _harvest_decode(self, rec: _InFlightDecode):
-        """Block on the step's token vector and advance host state.
+        """Block on the step's token vector, then advance host state
+        under the `advance` span (the rest of `harvest` is the wait).
         Slots in `rec.slots` cannot have been released in between: the
         only release site is this harvest."""
         nxt = np.asarray(rec.tokens)  # the pipeline's blocking point
-        now = time.perf_counter()
-        for slot in rec.slots:
-            st = self.active[slot]
-            tok = int(nxt[slot])
-            st.tokens.append(tok)
-            st.last_token = tok
-            st.pos += 1
-            st.emit_times.append(now)  # the token's host-visible stamp
-            self.arena.advance(slot)
-            if self._prefix_on and st.pos % self._page_size == 0:
-                # a page just filled (positions [0, pos) are written
-                # and final): publish it.  This is what keeps a later
-                # preemption victim's pages warm through its release —
-                # the resume re-prefills only the unregistered tail.
-                self.arena.register_prefix(
-                    slot,
-                    np.concatenate(
-                        [
-                            st.request.prompt,
-                            np.asarray(st.tokens, np.int32),
-                        ]
-                    ),
-                    st.pos,
-                )
-            self._emit(st.request, tok, slot)
-            self._maybe_finish(st, now)
+        with self.tel.span("advance"):
+            now = time.perf_counter()
+            for slot in rec.slots:
+                st = self.active[slot]
+                tok = int(nxt[slot])
+                st.tokens.append(tok)
+                st.last_token = tok
+                st.pos += 1
+                st.emit_times.append(now)  # the token's host-visible stamp
+                self.arena.advance(slot)
+                if self._prefix_on and st.pos % self._page_size == 0:
+                    # a page just filled (positions [0, pos) are written
+                    # and final): publish it.  This is what keeps a later
+                    # preemption victim's pages warm through its release —
+                    # the resume re-prefills only the unregistered tail.
+                    self.arena.register_prefix(
+                        slot,
+                        np.concatenate(
+                            [
+                                st.request.prompt,
+                                np.asarray(st.tokens, np.int32),
+                            ]
+                        ),
+                        st.pos,
+                    )
+                self._emit(st.request, tok, slot)
+                self._maybe_finish(st, now)
 
     def run_until_drained(
         self, max_steps: int = 1_000_000
@@ -1084,8 +1087,9 @@ class ServingEngine:
         )
 
     def _harvest_unified(self, rec: _InFlightStep):
-        """Block on the step's token vector and advance host state for
-        both row kinds.  Decode slots in `rec.decode_slots` cannot have
+        """Block on the step's token vector, then advance host state
+        for both row kinds under the `advance` span (the rest of
+        `harvest` is the wait).  Decode slots in `rec.decode_slots` cannot have
         been released in between (the only release site is a harvest);
         chunk rows advance their cursors and graduate when their final
         chunk just completed — a graduating row's first decode rides
@@ -1093,50 +1097,51 @@ class ServingEngine:
         instead of emitting a first token (¶Preemption
         bit-exactness)."""
         nxt = np.asarray(rec.tokens)  # the pipeline's blocking point
-        now = time.perf_counter()
-        for slot in rec.decode_slots:
-            st = self.active[slot]
-            tok = int(nxt[slot])
-            st.tokens.append(tok)
-            st.last_token = tok
-            st.pos += 1
-            st.emit_times.append(now)
-            self.arena.advance(slot)
-            if self._prefix_on and st.pos % self._page_size == 0:
-                # a page just filled (positions [0, pos) are written
-                # and final): publish it — see _harvest_decode
-                self.arena.register_prefix(
-                    slot,
-                    np.concatenate(
-                        [
-                            st.request.prompt,
-                            np.asarray(st.tokens, np.int32),
-                        ]
-                    ),
-                    st.pos,
-                )
-            self._emit(st.request, tok, slot)
-            self._maybe_finish(st, now)
-        for st, off, n in rec.chunk_plan:
-            self.arena.advance(st.slot, n)
-            if self._prefix_on:
-                # the chunk completed every position below off + n:
-                # its full pages are final — publish them so later
-                # requests (and this request's own resume) share them
-                self.arena.register_prefix(st.slot, st.source, off + n)
-            if off + n < st.source_len:
-                st.offset = off + n  # carried into the next dispatch
-                continue
-            del self.prefilling[st.slot]  # final chunk completed
-            if st.resume is not None:
-                self._resume_decoding(
-                    st.request, st.slot, int(nxt[st.slot]), now, st.resume
-                )
-            else:
-                self._start_decoding(
-                    st.request, st.slot, int(nxt[st.slot]), now,
-                    st.admit_time,
-                )
+        with self.tel.span("advance"):
+            now = time.perf_counter()
+            for slot in rec.decode_slots:
+                st = self.active[slot]
+                tok = int(nxt[slot])
+                st.tokens.append(tok)
+                st.last_token = tok
+                st.pos += 1
+                st.emit_times.append(now)
+                self.arena.advance(slot)
+                if self._prefix_on and st.pos % self._page_size == 0:
+                    # a page just filled (positions [0, pos) are written
+                    # and final): publish it — see _harvest_decode
+                    self.arena.register_prefix(
+                        slot,
+                        np.concatenate(
+                            [
+                                st.request.prompt,
+                                np.asarray(st.tokens, np.int32),
+                            ]
+                        ),
+                        st.pos,
+                    )
+                self._emit(st.request, tok, slot)
+                self._maybe_finish(st, now)
+            for st, off, n in rec.chunk_plan:
+                self.arena.advance(st.slot, n)
+                if self._prefix_on:
+                    # the chunk completed every position below off + n:
+                    # its full pages are final — publish them so later
+                    # requests (and this request's own resume) share them
+                    self.arena.register_prefix(st.slot, st.source, off + n)
+                if off + n < st.source_len:
+                    st.offset = off + n  # carried into the next dispatch
+                    continue
+                del self.prefilling[st.slot]  # final chunk completed
+                if st.resume is not None:
+                    self._resume_decoding(
+                        st.request, st.slot, int(nxt[st.slot]), now, st.resume
+                    )
+                else:
+                    self._start_decoding(
+                        st.request, st.slot, int(nxt[st.slot]), now,
+                        st.admit_time,
+                    )
 
     def _start_decoding(self, req: Request, slot: int, first: int,
                         now: float, admit_time: float):

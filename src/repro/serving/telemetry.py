@@ -14,17 +14,19 @@ OFF by default and all **bit-neutral** by construction:
   serving run, not a sample of one.
 
   step-phase spans — a context-manager span per engine-step phase
-  (`admission`, `plan_chunks`, `unified_dispatch`, `decode_dispatch`,
-  `harvest`), aggregated into one per-step record
+  (`PHASES`), aggregated into one per-step record
   together with dispatch-queue depth, compile-cache hit/miss counters,
   and the arena's instantaneous gauges (slot occupancy, pages in use /
   high water, backpressure rejections) — DESIGN.md §Observability
   ¶Span model.
 
-  profiler hooks — `annotate()` optionally wraps each device dispatch
-  in `jax.profiler.TraceAnnotation`, so device traces line up with the
-  host-side spans (off unless `profile_annotations=True`: annotation
-  context entry is not free on the per-step path).
+  profiler hooks — with `profile_annotations=True` each engine step is
+  a `jax.profiler.StepTraceAnnotation` named `STEP_SPAN`, each phase
+  span a `TraceAnnotation` named `phase/<phase>`, and `annotate()`
+  wraps each device dispatch (`repro.serving/unified`, ...), so the
+  host's phases land on the profiler's clock beside the device's
+  programs.  Off by default: an annotation is allocated and entered
+  per span, which is not free on the per-step path.
 
 Bit-neutrality (DESIGN.md §Observability ¶Bit-neutrality): every hook
 reads HOST state only — wall-clock stamps, python counters, the
@@ -79,18 +81,26 @@ EVENT_FIELDS: Dict[str, frozenset] = {
 # ¶Span model).  `unified_dispatch` is the chunked-mode step's single
 # fused dispatch (decode + prefill rows in one kernel call — DESIGN.md
 # §Serving ¶Unified attention kernel); `decode_dispatch` survives on
-# the non-chunked (bucketed/exact) oracle paths.  Under async dispatch
-# (depth 1) `harvest` covers the drain of the PREVIOUS step's
-# in-flight dispatch — the pipeline's one blocking point — so a fat
-# `harvest` there is device time the host successfully overlapped,
-# not host work.
+# the non-chunked (bucketed/exact) oracle paths.  `harvest` covers the
+# wait for a dispatch's token vector AND `advance`, nested inside it:
+# the host bookkeeping once the tokens are on the host (emits,
+# finishes, prefix registration, chunk cursors).  So harvest's own
+# time (harvest - advance) is the wait.  Under async dispatch (depth
+# 1) `harvest` covers the drain of the PREVIOUS step's in-flight
+# dispatch — the pipeline's one blocking point — so a fat `harvest`
+# there is device time the host successfully overlapped, not host work.
 PHASES: Tuple[str, ...] = (
     "admission",
     "plan_chunks",
     "unified_dispatch",
     "decode_dispatch",
     "harvest",
+    "advance",
 )
+
+# The engine step's name in the profiler trace (DESIGN.md
+# §Observability ¶Span model); a phase's is `phase/<phase>`.
+STEP_SPAN = "repro.serving/step"
 
 
 class _NullCtx:
@@ -169,6 +179,28 @@ class _Span:
         return False
 
 
+class _AnnotatedSpan(_Span):
+    """A `_Span` that also writes `phase/<phase>` into the profiler
+    trace.  A TraceAnnotation records one interval per object, so each
+    entry makes a new one."""
+
+    __slots__ = ("name", "ann")
+
+    def __init__(self, tel: "Telemetry", phase: str):
+        super().__init__(tel, phase)
+        self.name = "phase/" + phase
+
+    def __enter__(self):
+        self.ann = self.tel._annotation(self.name)
+        self.ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        self.ann.__exit__(*exc)
+        return False
+
+
 class Telemetry:
     """Buffering telemetry sink (DESIGN.md §Observability).
 
@@ -186,6 +218,14 @@ class Telemetry:
 
     def __init__(self, *, profile_annotations: bool = False):
         self.profile_annotations = bool(profile_annotations)
+        self._span_cls = _Span
+        if self.profile_annotations:
+            from jax import profiler
+
+            self._annotation = profiler.TraceAnnotation
+            self._step_annotation = profiler.StepTraceAnnotation
+            self._span_cls = _AnnotatedSpan
+        self._step_ann = None
         self.clock = time.perf_counter
         self.events: List[dict] = []
         self.steps: List[dict] = []
@@ -213,6 +253,9 @@ class Telemetry:
 
     # -- step spans + gauges -------------------------------------------
     def begin_step(self, idx: int):
+        if self.profile_annotations:
+            self._step_ann = self._step_annotation(STEP_SPAN, step_num=idx)
+            self._step_ann.__enter__()
         self._step_idx = idx
         self._cur = {"step": idx, "t": self.clock(), "phases": {}}
 
@@ -221,7 +264,7 @@ class Telemetry:
         (reused per phase — see __init__)."""
         s = self._spans.get(phase)
         if s is None:
-            s = self._spans[phase] = _Span(self, phase)
+            s = self._spans[phase] = self._span_cls(self, phase)
         return s
 
     def end_step(self, **gauges):
@@ -237,6 +280,9 @@ class Telemetry:
         self.steps.append(cur)
         self._cur = None
         self._step_idx = None
+        if self._step_ann is not None:
+            self._step_ann.__exit__(None, None, None)
+            self._step_ann = None
 
     # -- compile-cache counters ----------------------------------------
     def dispatch(self, kind: str, key):
@@ -260,11 +306,7 @@ class Telemetry:
         no-op context."""
         if not self.profile_annotations:
             return _NULL_CTX
-        try:
-            from jax.profiler import TraceAnnotation
-        except ImportError:  # pragma: no cover - jax is a hard dep
-            return _NULL_CTX
-        return TraceAnnotation(name)
+        return self._annotation(name)
 
     # -- export --------------------------------------------------------
     def clear(self):

@@ -52,13 +52,15 @@ def _workload(vocab, rng=None):
 
 
 def _run(lm, tables, workload, *, telemetry=None, paged=False,
-         dispatch_depth=0, n_slots=3, n_pages=None, warmup=False):
+         dispatch_depth=0, n_slots=3, n_pages=None, warmup=False,
+         prefill_chunk=4):
     eng = make_engine(
         lm, tables, n_slots=n_slots, max_len=MAX_LEN, paged=paged,
         page_size=8, n_pages=n_pages, dispatch_depth=dispatch_depth,
         telemetry=telemetry,
         scheduler=SchedulerConfig(max_prefills_per_step=2,
-                                  prefill_bucket=8, prefill_chunk=4))
+                                  prefill_bucket=8,
+                                  prefill_chunk=prefill_chunk))
     if warmup:
         eng.warmup()
     ids = []
@@ -72,17 +74,20 @@ def _run(lm, tables, workload, *, telemetry=None, paged=False,
 # ---------------------------------------------------------------------
 # bit-neutrality: telemetry must never change a token
 # ---------------------------------------------------------------------
+@pytest.mark.parametrize("profile_annotations", [False, True])
 @pytest.mark.parametrize("paged", [False, True])
 @pytest.mark.parametrize("dispatch_depth", [0, 1])
-def test_bit_neutrality(deployed, paged, dispatch_depth):
+def test_bit_neutrality(deployed, paged, dispatch_depth,
+                        profile_annotations):
     """Telemetry-on and telemetry-off engines produce token-for-token
-    identical output on both arenas, sync and async — the hooks read
-    host state only (DESIGN.md §Observability ¶Bit-neutrality)."""
+    identical output on both arenas, sync and async, with profiler
+    annotations on or off — the hooks read host state only (DESIGN.md
+    §Observability ¶Bit-neutrality)."""
     lm, tables = deployed
     w = _workload(lm.cfg.vocab)
     off_toks, off_eng = _run(lm, tables, w, paged=paged,
                              dispatch_depth=dispatch_depth)
-    tel = Telemetry()
+    tel = Telemetry(profile_annotations=profile_annotations)
     on_toks, on_eng = _run(lm, tables, w, telemetry=tel, paged=paged,
                            dispatch_depth=dispatch_depth)
     assert on_toks == off_toks
@@ -176,6 +181,28 @@ def test_step_records_phases_and_gauges(deployed):
     m = tel.metrics()
     assert m["n_steps"] == len(tel.steps)
     assert set(m["phase_mean_s"]) == seen_phases
+
+
+@pytest.mark.parametrize("prefill_chunk", [4, 0])
+@pytest.mark.parametrize("paged", [False, True])
+@pytest.mark.parametrize("dispatch_depth", [0, 1])
+def test_advance_is_inside_harvest(deployed, paged, dispatch_depth,
+                                   prefill_chunk):
+    """`advance` (the host bookkeeping once the tokens are on the host)
+    is timed inside `harvest` in every step that records it, so it
+    never exceeds harvest's time: harvest - advance is the wait for the
+    token vector.  Both arenas, sync and async, unified and decode
+    harvests."""
+    lm, tables = deployed
+    tel = Telemetry()
+    _run(lm, tables, _workload(lm.cfg.vocab), telemetry=tel, paged=paged,
+         dispatch_depth=dispatch_depth, prefill_chunk=prefill_chunk)
+    with_advance = [s["phases"] for s in tel.steps
+                    if "advance" in s["phases"]]
+    assert with_advance, "no advance span recorded"
+    for ph in with_advance:
+        assert "harvest" in ph
+        assert 0.0 <= ph["advance"] <= ph["harvest"]
 
 
 def test_compile_cache_accounting_after_warmup(deployed):
@@ -331,6 +358,71 @@ def test_profile_annotations_smoke(deployed):
     from repro.serving.telemetry import _NULL_CTX
     assert tel.annotate("x") is not _NULL_CTX
     assert Telemetry().annotate("x") is _NULL_CTX
+
+
+def test_annotations_off_enter_nothing(deployed, monkeypatch):
+    """With telemetry off, or on without profile_annotations, the
+    engine enters no profiler annotation at all; with them on it
+    does."""
+    import jax.profiler
+
+    entered = []
+
+    def counting(base):
+        class Counting(base):
+            def __enter__(self):
+                entered.append(base)
+                return super().__enter__()
+
+        return Counting
+
+    originals = {name: getattr(jax.profiler, name)
+                 for name in ("TraceAnnotation", "StepTraceAnnotation")}
+    for name, base in originals.items():
+        monkeypatch.setattr(jax.profiler, name, counting(base))
+    lm, tables = deployed
+    w = _workload(lm.cfg.vocab)
+    for telemetry in (None, Telemetry(),
+                      Telemetry(profile_annotations=False)):
+        _run(lm, tables, w, telemetry=telemetry, paged=True)
+        assert entered == []
+    _run(lm, tables, w, telemetry=Telemetry(profile_annotations=True),
+         paged=True)
+    assert set(entered) == set(originals.values())
+
+
+def test_profiler_trace_holds_the_engine_spans(deployed, tmp_path):
+    """A profiler trace of a served run with profile_annotations=True
+    holds the step annotation, `phase/harvest` with `phase/advance`
+    nested inside it, and the dispatch's `repro.serving/unified`."""
+    import glob
+
+    import jax.profiler
+    from jax.profiler import ProfileData
+
+    lm, tables = deployed
+    tel = Telemetry(profile_annotations=True)
+    with jax.profiler.trace(str(tmp_path)):
+        _run(lm, tables, _workload(lm.cfg.vocab)[:2], telemetry=tel,
+             paged=True)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    steps = spans.get("repro.serving/step", [])
+    assert len(steps) == len(tel.steps)
+    assert spans.get("repro.serving/unified")
+    harvests, advances = spans["phase/harvest"], spans["phase/advance"]
+    assert advances
+    for a, b in advances:
+        assert any(h0 <= a and b <= h1 for h0, h1 in harvests)
+    for a, b in harvests:
+        assert any(s0 <= a and b <= s1 for s0, s1 in steps)
 
 
 def test_submit_requires_engine_stamp():
