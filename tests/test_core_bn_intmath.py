@@ -11,7 +11,7 @@ from repro.core.bn import (
 )
 from repro.core.intmath import (
     apply_lut, avgpool_requant_params, build_lut, int_avgpool_combine,
-    int_isqrt, int_reciprocal_q,
+    int_isqrt, int_reciprocal_q, select_lut,
 )
 
 RNG = np.random.default_rng(0)
@@ -114,6 +114,65 @@ def test_lut_matches_fn():
     real_in = (np.arange(-128, 128) - zp_in) * eps_in
     expect = np.clip(np.round(silu(real_in) / eps_out) + zp_out, -128, 127)
     np.testing.assert_array_equal(out, expect)
+
+
+def _lut_tables():
+    silu = lambda v: v / (1.0 + np.exp(-v))
+    gelu = lambda v: 0.5 * v * (
+        1.0 + np.tanh(0.7978845608 * (v + 0.044715 * v**3)))
+    k = np.arange(256)
+    rng = np.random.default_rng(7)
+    return {
+        "silu": build_lut(silu, 0.05, -10, 0.021, -128),
+        "gelu": build_lut(gelu, 0.04, 0, 0.03, -100),
+        "random_a": rng.integers(-128, 128, 256).astype(np.int8),
+        "random_b": rng.integers(-128, 128, 256).astype(np.int8),
+        "non_monotone": np.round(100 * np.sin(k / 9.0)).astype(np.int8),
+        "constant": np.full(256, 37, np.int8),
+        "jumps": np.where(k % 2 == 0, -128, 127).astype(np.int8),
+    }
+
+
+LUT_TABLES = _lut_tables()
+
+
+def _scan_lookup(stored, table):
+    """The table as a traced slice of a per-layer stack under lax.scan."""
+    stack = jnp.stack([table, table[::-1]])
+
+    def layer(carry, t):
+        return carry, select_lut(stored, t)
+
+    return jax.lax.scan(layer, 0, stack)[1]
+
+
+@pytest.mark.parametrize("mode", ["int8", "int32", "multidim", "jit", "scan"])
+@pytest.mark.parametrize("name", sorted(LUT_TABLES))
+def test_select_lut_equals_gather(name, mode):
+    """The gather-free lookup is table[stored - qmin] for all 256 inputs."""
+    table = LUT_TABLES[name]
+    stored = np.arange(-128, 128)
+    np.random.default_rng(1).shuffle(stored)
+    if mode == "int32":
+        got = select_lut(jnp.asarray(stored, jnp.int32), table)
+    elif mode == "multidim":
+        stored = stored.reshape(4, 8, 8)
+        got = select_lut(jnp.asarray(stored, jnp.int8), table)
+    elif mode == "jit":
+        got = jax.jit(select_lut)(jnp.asarray(stored, jnp.int8), table)
+    elif mode == "scan":
+        got, got_rev = jax.jit(_scan_lookup)(
+            jnp.asarray(stored, jnp.int8), table)
+        np.testing.assert_array_equal(
+            np.asarray(got_rev), table[::-1][stored + 128])
+    else:
+        got = select_lut(jnp.asarray(stored, jnp.int8), table)
+    assert got.dtype == jnp.int8 and got.shape == stored.shape
+    np.testing.assert_array_equal(np.asarray(got), table[stored + 128])
+    np.testing.assert_array_equal(
+        np.asarray(apply_lut(jnp.asarray(stored, jnp.int8), table)),
+        table[stored + 128],
+    )
 
 
 def test_integer_avgpool():

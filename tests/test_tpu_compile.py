@@ -1,23 +1,27 @@
-"""Compile the paged-attention kernel for a described TPU v5e.
+"""Compile the paged kernel and the table lookup for a described TPU v5e.
 
 Nothing runs here: each test lowers the kernel at granite_3_2b's
 attention widths (32 query heads, 8 kv heads, head_dim 64) for one chip
 of a v5e topology and compiles it with the TPU compiler, which refuses
 what the Pallas interpreter accepts — tiles not aligned to the
 hardware, more VMEM than a kernel may use.  The HLO must hold the
-Mosaic kernel (`tpu_custom_call`), not the interpreter's loops.
+Mosaic kernel (`tpu_custom_call`), not the interpreter's loops.  The
+MLP gate's table lookup, at granite_3_2b's chunk-step width, must
+compile to no gather for the TPU and keep its gather on the CPU.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.intmath import apply_lut
 from repro.kernels.paged_attention import (
     TPU_PAGE_ALIGN,
     paged_attention_pallas,
@@ -108,3 +112,27 @@ def test_paged_kernel_compiles_for_v5e(
     mem = compiled.memory_analysis()
     pool_bytes = 2 * (n_pages + 1) * K * HD * PS // (2 if packed else 1)
     assert mem.argument_size_in_bytes >= pool_bytes
+
+
+# 32 slots x a 128-wide chunk x granite_3_2b's d_ff: the SiLU gate's
+# stored values in one chunk step
+GATE = (32, 128, 8192)
+GATHER = re.compile(r"\bgather\(")
+
+
+def _lookup_hlo(**sharding):
+    stored = jax.ShapeDtypeStruct(GATE, jnp.int8, **sharding)
+    table = jax.ShapeDtypeStruct((256,), jnp.int8, **sharding)
+    return jax.jit(apply_lut).lower(stored, table).compile().as_text()
+
+
+def test_lut_lookup_compiles_without_gather_for_v5e(
+    one_chip, no_persistent_cache
+):
+    hlo = _lookup_hlo(sharding=one_chip)
+    assert "select" in hlo
+    assert not GATHER.search(hlo)
+
+
+def test_lut_lookup_keeps_gather_on_cpu():
+    assert GATHER.search(_lookup_hlo())
