@@ -2,8 +2,9 @@
 
 Once the window has closed and the program's state is freed, a sample
 of the requests the run finished, drawn from the seed and holding the
-finished request with the most served tokens, is run through
-`harness.reference` over its prompt and served tokens.  At every served
+finished request with the most served tokens, is run through the
+configuration's own reference (`harness.reference`) over its prompt
+and served tokens.  At every served
 token the gap by which its logit lies below the reference's best logit
 at that position is read.  The number compared is the mean gap over
 every served token of the sample, against the limit in
@@ -32,19 +33,20 @@ def sample(served: Dict[int, tuple], prompts: Dict[int, np.ndarray],
     return [longest] + rest[:n_requests - 1]
 
 
-def gaps(key, dims, prompts, served, ids, length: int, *,
+def gaps(ref, key, dims, prompts, served, ids, length: int, *,
          bits=None) -> np.ndarray:
-    """Every served token's gap below the reference's best, over `ids`;
-    with `bits`, the control's own choice at each of those positions."""
+    """Every served token's gap below the best of reference module
+    `ref`, over `ids`; with `bits`, the control's own choice at each of
+    those positions."""
     out = []
     for r in ids:
         toks = np.asarray(served[r][0], np.int32)
         if bits is None:
-            g = reference.served_gaps(key, dims, prompts[r], toks,
+            g = reference.served_gaps(ref, key, dims, prompts[r], toks,
                                       length=length)
         else:
-            g = reference.control_gaps(key, dims, prompts[r], toks, bits,
-                                       length=length)
+            g = reference.control_gaps(ref, key, dims, prompts[r], toks,
+                                       bits, length=length)
         out.append(g)
     return np.concatenate(out) if out else np.zeros(0)
 

@@ -1,13 +1,13 @@
 """Build the program's model and its integer tables for a configuration.
 
-The first run in a checkout makes the float weights on the device
-(`harness.weights`), has the program calibrate and deploy them
-(`DecoderLM.calibrate`, `DecoderLM.deploy`: the tables are built on the
-host), and saves the tables under `bench/.state/`, one file per key,
-written atomically.  Every later run loads that file.  The key holds
-the configuration's name and sizes, `max_len`, `kv_bits` and a digest
-of the program's source and of the weight generator, so a change to
-either deploys afresh.
+The first run in a checkout makes the float weights on the device (the
+configuration's reference module, `program_params`), has the program
+calibrate and deploy them (`DecoderLM.calibrate`, `DecoderLM.deploy`:
+the tables are built on the host), and saves the tables under
+`bench/.state/`, one file per key, written atomically.  Every later run
+loads that file.  The key holds the configuration's name and sizes,
+`max_len`, `kv_bits` and a digest of the program's source and of the
+reference module, so a change to either deploys afresh.
 """
 from __future__ import annotations
 
@@ -21,15 +21,15 @@ from pathlib import Path
 import numpy as np
 
 from harness.spec import BENCH, ROOT
-from harness.weights import Dims, program_params
 
 STATE = BENCH / ".state"
 CALIB_BATCH, CALIB_LEN = 4, 64  # the calibration sample deploy_model uses
 
 
-def arch_config(config: dict):
+def arch_config(config: dict, ref):
     """The program's ArchConfig for `config`, cut as the file says and
-    registered under the configuration's own name."""
+    registered under the configuration's own name, and the sizes of
+    reference `ref`; raises where the two are not one model."""
     from repro.configs.base import get_config, register
 
     prog = config["program"]
@@ -37,30 +37,22 @@ def arch_config(config: dict):
     cfg = dataclasses.replace(base, name=config["name"],
                               **prog.get("overrides", {}))
     register(cfg)
-    dims = Dims.from_config(config["model"])
-    have = Dims(
-        n_layers=cfg.n_layers, d_model=cfg.d_model, n_heads=cfg.n_heads,
-        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd, d_ff=cfg.d_ff,
-        vocab=cfg.vocab, rope_theta=cfg.rope_base,
-        rope_fraction=cfg.rope_fraction, norm_eps=dims.norm_eps,
-    )
-    if have != dims:
-        raise ValueError(f"program config {have} differs from {dims}")
-    if cfg.family != "dense" or not cfg.gated or cfg.act != "silu":
-        raise ValueError("the reference models a dense SwiGLU decoder")
+    dims = ref.Dims.from_config(config["model"])
+    ref.check_program(cfg, dims)
     return cfg, dims
 
 
-def _digest(config: dict, max_len: int, kv_bits: int) -> str:
+def _digest(config: dict, ref, max_len: int, kv_bits: int) -> str:
     h = hashlib.sha256()
     h.update(json.dumps([config["model"], config["program"],
                          config["weights_seed"], max_len, kv_bits],
                         sort_keys=True).encode())
-    files = sorted((ROOT / "src" / "repro").rglob("*.py"))
-    files.append(BENCH / "harness" / "weights.py")
-    for f in files:
+    for f in sorted((ROOT / "src" / "repro").rglob("*.py")):
         h.update(str(f.relative_to(ROOT)).encode())
         h.update(f.read_bytes())
+    f = Path(ref.__file__)
+    h.update(f.name.encode())
+    h.update(f.read_bytes())
     return h.hexdigest()[:16]
 
 
@@ -118,7 +110,7 @@ def _load(path: Path):
     return root
 
 
-def _deploy(config, cfg, dims, max_len):
+def _deploy(config, ref, cfg, dims, max_len):
     import jax
     import jax.numpy as jnp
 
@@ -127,7 +119,7 @@ def _deploy(config, cfg, dims, max_len):
 
     lm = DecoderLM(cfg, max_seq=max_len)
     key = jax.random.PRNGKey(config["weights_seed"])
-    p = jax.jit(program_params, static_argnums=(1, 2))(
+    p = jax.jit(ref.program_params, static_argnums=(1, 2))(
         key, dims, cfg.vocab_padded)
     want = jax.eval_shape(lm.init, key)
     if jax.tree.structure(want) != jax.tree.structure(p) or any(
@@ -144,23 +136,23 @@ def _deploy(config, cfg, dims, max_len):
     return tables
 
 
-def tables(config: dict, max_len: int, kv_bits: int, log):
-    """-> (lm, device tables, seconds spent deploying or 0.0)."""
+def tables(config: dict, ref, max_len: int, kv_bits: int, log):
+    """-> (lm, device tables, seconds spent deploying or 0.0), for
+    `config` with its reference module `ref`."""
     import jax
     import jax.numpy as jnp
 
     from repro.models.lm import DecoderLM
 
-    cfg, _ = arch_config(config)
-    dims = Dims.from_config(config["model"])
+    cfg, dims = arch_config(config, ref)
     path = STATE / (f"{config['name']}-{max_len}-kv{kv_bits}-"
-                    f"{_digest(config, max_len, kv_bits)}.npz")
+                    f"{_digest(config, ref, max_len, kv_bits)}.npz")
     deploy_s = 0.0
     if path.is_file():
         host = _load(path)
     else:
         t0 = time.perf_counter()
-        host = _deploy(config, cfg, dims, max_len)
+        host = _deploy(config, ref, cfg, dims, max_len)
         _save(path, host)
         deploy_s = time.perf_counter() - t0
         log(f"deploy_s {deploy_s} (tables saved to {path.name})")

@@ -80,7 +80,8 @@ def attempted_failed(win, served, seconds, open_loop: bool) -> tuple:
 class Run:
     """What a per-layer reader gets: the window, what was served, the
     reduced device trace (None without --trace 1), the model's sizes,
-    the engine geometry and the device's peaks."""
+    the engine geometry, the device's peaks, and the configuration's
+    reference module."""
 
     window: object
     served: Dict[int, tuple]
@@ -90,6 +91,7 @@ class Run:
     engine: dict
     peaks: dict
     counts: object  # harness.spec.counts
+    reference: object  # bench/references/<name>.py
 
     def rows(self, d) -> list:
         """(start, n) of each real row of dispatch `d`."""
@@ -119,9 +121,9 @@ def idle_share(run) -> Optional[float]:
 
 def step_mfu(run) -> Optional[float]:
     """% of the chip's int8 peak: the integer operations the window's
-    steps needed (counts/model_step.py) over traced window x peak."""
-    ms = run.counts("model_step")
-    ops = sum(ms.step_ops(run.rows(d), run.dims)
+    steps needed (the reference's `step_ops`) over traced window x
+    peak."""
+    ops = sum(run.reference.step_ops(run.rows(d), run.dims)
               for d in run.window.dispatches)
     w = run.trace.window_s
     return 100.0 * ops / (w * run.peaks["int8_ops_s"]) if w > 0 else None

@@ -5,7 +5,11 @@ of those, each per-layer metric's reader and each kernel's counts is a
 file of its own under `bench/`, found here by its name alone, so that a
 cell, a mix or a metric is added by adding files:
 
-  bench/configs/<config>.json    model sizes, source, cuts, engine
+  bench/configs/<config>.json    model sizes, source, cuts, engine,
+                                 and the name of its reference
+  bench/references/<name>.py     the model's plain reference: weights,
+                                 float32 forward pass, step operations
+                                 (the contract: harness/reference.py)
   bench/traffic/<traffic>.json   arrival process and length mixes
   bench/checks/<workload>.json   the limits of the correctness check
   bench/metrics/<metric>.py      read(run) -> number or None
@@ -17,6 +21,8 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import os
+import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
@@ -29,16 +35,17 @@ class SpecError(Exception):
 
 def _json(path: Path) -> dict:
     if not path.is_file():
-        raise SpecError(f"missing {path.relative_to(ROOT)}")
+        raise SpecError(f"missing {os.path.relpath(path, ROOT)}")
     with open(path) as f:
         return json.load(f)
 
 
 def _module(path: Path, name: str):
     if not path.is_file():
-        raise SpecError(f"missing {path.relative_to(ROOT)}")
+        raise SpecError(f"missing {os.path.relpath(path, ROOT)}")
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod  # a dataclass looks its module up there
     spec.loader.exec_module(mod)
     return mod
 
@@ -48,6 +55,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    reference: object  # the module bench/references/<name>.py
     traffic: dict
     check: dict
     end_to_end: list  # BENCHMARK.json entries this cell reports
@@ -78,15 +86,31 @@ def cell(name: str, root: Path = ROOT) -> Cell:
     e2e_names = {m["name"] for m in e2e}
     per_layer = [m for m in bm["per_layer"]
                  if _reports(m, name) and m["moves"] in e2e_names]
+    config = load("configs", w["config"], root)
     return Cell(
         name=name,
         chips=int(w["chips"]),
-        config=load("configs", w["config"], root),
+        config=config,
+        reference=config_reference(config, root),
         traffic=load("traffic", w["traffic"], root),
         check=load("checks", name, root),
         end_to_end=e2e,
         per_layer=per_layer,
     )
+
+
+def reference(name: str, root: Path = ROOT):
+    """The plain-reference module `name`."""
+    return _module(root / "bench" / "references" / f"{name}.py",
+                   "reference_" + name)
+
+
+def config_reference(config: dict, root: Path = ROOT):
+    """The reference module that configuration `config` names."""
+    if "reference" not in config:
+        raise SpecError(f"configuration {config['name']!r} names no "
+                        "reference")
+    return reference(config["reference"], root)
 
 
 def metric_reader(name: str, root: Path = ROOT):

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import glob
+import heapq
 import os
 import re
 from typing import Dict, List, Optional, Tuple
@@ -133,11 +134,22 @@ def idle_gaps(tr: Trace, n: int = 10) -> List[list]:
         t = max(t, b)
     if tr.t1 > t:
         gaps.append((t, tr.t1))
+    # one sweep over the gaps in time order: `open_` holds, shortest
+    # first (the first listed on a tie), the spans begun by the gap's
+    # middle; one that ended before it can never cover a later middle
+    spans = sorted(range(len(tr.host)), key=lambda i: tr.host[i][1])
+    open_: List[Tuple[float, int]] = []
+    nxt = 0
     tot: Dict[str, float] = {}
     for a, b in gaps:
         mid = (a + b) / 2
-        over = [s for s in tr.host if s[1] <= mid <= s[2]]
-        label = min(over, key=lambda s: s[2] - s[1])[0] if over else "host"
+        while nxt < len(spans) and tr.host[spans[nxt]][1] <= mid:
+            _, s0, s1 = tr.host[spans[nxt]]
+            heapq.heappush(open_, (s1 - s0, spans[nxt]))
+            nxt += 1
+        while open_ and tr.host[open_[0][1]][2] < mid:
+            heapq.heappop(open_)
+        label = tr.host[open_[0][1]][0] if open_ else "host"
         tot[label] = tot.get(label, 0.0) + (b - a)
     return [[k, v] for k, v in sorted(tot.items(), key=lambda kv: -kv[1])[:n]]
 

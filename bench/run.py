@@ -67,6 +67,12 @@ def phase_telemetry():
 def main(argv=None) -> int:
     args = parse(argv)
     sys.path[:0] = [os.path.join(ROOT, "src"), BENCH]
+    # JAX's persistent compilation cache at a fixed path in the checkout,
+    # unless the environment names one; the program reads the same
+    # variable and keeps to it.  JAX reads it as it is imported, and
+    # resolving the cell imports it (the reference module)
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                          os.path.join(ROOT, ".jax_cache"))
     from harness import spec
 
     try:
@@ -74,11 +80,6 @@ def main(argv=None) -> int:
     except spec.SpecError as e:
         log(str(e))
         return 2
-    # JAX's persistent compilation cache at a fixed path in the checkout,
-    # unless the environment names one; the program reads the same
-    # variable and keeps to it
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          os.path.join(ROOT, ".jax_cache"))
     from repro.launch.compile_cache import setup_compile_cache
 
     setup_compile_cache()
@@ -112,8 +113,8 @@ def serve(args, cell, devs) -> dict:
     open_loop = mix["loop"] == "open"
     if traffic.max_context(mix) > eng["max_len"]:
         raise ValueError("the mix's longest request exceeds max_len")
-    lm, tables, deploy_s = deploy.tables(cell.config, eng["max_len"],
-                                         eng["kv_bits"], log)
+    lm, tables, deploy_s = deploy.tables(cell.config, cell.reference,
+                                         eng["max_len"], eng["kv_bits"], log)
     engine = ServingEngine(lm, tables, ServingConfig(
         n_slots=eng["n_slots"], max_len=eng["max_len"], paged=True,
         page_size=eng["page_size"], n_pages=eng["n_pages"],
@@ -189,20 +190,19 @@ def gaps(cell, got: dict, seed: int, bits=None):
     import jax
 
     from harness import check, reference, traffic
-    from harness.weights import Dims
 
-    dims = Dims.from_config(cell.config["model"])
+    ref = cell.reference
+    dims = ref.Dims.from_config(cell.config["model"])
     key = jax.random.PRNGKey(cell.config["weights_seed"])
     ids = check.sample(got["served"], got["prompts"], seed,
                        cell.check["sample_requests"])
     length = reference.padded_length(traffic.max_context(cell.traffic))
-    return ids, check.gaps(key, dims, got["prompts"], got["served"], ids,
-                           length, bits=bits)
+    return ids, check.gaps(ref, key, dims, got["prompts"], got["served"],
+                           ids, length, bits=bits)
 
 
 def run(args, cell, dev, devs, peaks) -> int:
     from harness import check, measure, spec, xtrace
-    from harness.weights import Dims
 
     got = serve(args, cell, devs)
     win = got["win"]
@@ -216,9 +216,10 @@ def run(args, cell, dev, devs, peaks) -> int:
         if tr is None:
             log("the trace holds no device window")
             return 1
+        ref = cell.reference
         ctx = measure.Run(win, got["served"], args.seconds, tr,
-                          Dims.from_config(cell.config["model"]),
-                          cell.config["engine"], peaks, spec.counts)
+                          ref.Dims.from_config(cell.config["model"]),
+                          cell.config["engine"], peaks, spec.counts, ref)
         metrics = {}
         for m in cell.per_layer:
             v = spec.metric_reader(m["name"])(ctx)

@@ -36,9 +36,10 @@ def main(argv=None) -> int:
     from harness import measure, spec
     from repro.launch.compile_cache import setup_compile_cache
 
+    config = spec.load("configs", args.config)
     cell = spec.Cell(
-        name=f"{args.config}.{args.traffic}", chips=1,
-        config=spec.load("configs", args.config),
+        name=f"{args.config}.{args.traffic}", chips=1, config=config,
+        reference=spec.config_reference(config),
         traffic=spec.load("traffic", args.traffic), check={},
         end_to_end=[], per_layer=[])
     setup_compile_cache()

@@ -67,11 +67,11 @@ def test_roofline_picks_the_larger_bound():
 
 
 def test_step_ops_granite_decode():
-    from harness.weights import Dims
+    from harness import spec
 
-    ms = _load("model_step")
-    d = Dims(n_layers=40, d_model=2048, n_heads=32, n_kv_heads=8,
-             head_dim=64, d_ff=8192, vocab=49155)
+    ms = spec.reference("dense_swiglu")
+    d = ms.Dims(n_layers=40, d_model=2048, n_heads=32, n_kv_heads=8,
+                head_dim=64, d_ff=8192, vocab=49155)
     per_layer = 2048 * (32 + 16) * 64 + 32 * 64 * 2048 + 3 * 2048 * 8192
     assert ms.layer_weights(d) == per_layer
     ops = ms.step_ops([(99, 1)], d)

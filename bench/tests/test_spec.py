@@ -8,10 +8,19 @@ import pytest
 from harness import spec
 
 
+CONTRACT = ("Dims", "program_params", "check_program", "embed", "layer",
+            "head_rows", "step_ops")
+
+
 def test_every_cell_resolves():
     bm = spec.benchmark()
+    for c in bm["configs"]:
+        with open(spec.ROOT / c["file"]) as f:
+            ref = spec.config_reference(json.load(f))
+        assert all(hasattr(ref, a) for a in CONTRACT), c["name"]
     for w in bm["workloads"]:
         cell = spec.cell(w["name"])
+        assert all(hasattr(cell.reference, a) for a in CONTRACT)
         assert cell.end_to_end and cell.per_layer
         names = [m["name"] for m in cell.end_to_end]
         assert "setup_s" in names
@@ -42,8 +51,9 @@ def test_configs_match_their_source():
 
 
 def test_a_cell_added_as_data_resolves(tmp_path):
-    """A later change adds a configuration, a mix, a check and a metric
-    as files, edits none, and the harness runs the new cell."""
+    """A later change adds a configuration with its reference, a mix, a
+    check and a metric as files, edits none, and the harness runs the
+    new cell."""
     root = tmp_path
     shutil.copytree(spec.BENCH, root / "bench",
                     ignore=shutil.ignore_patterns(".state", "__pycache__"))
@@ -58,7 +68,12 @@ def test_a_cell_added_as_data_resolves(tmp_path):
         "source": "program_counter", "layer": "scheduler",
         "moves": "itl_p95_s", "workloads": ["newcfg.burst"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bm))
-    shutil.copy(spec.ROOT / cfg0["file"], root / "bench/configs/newcfg.json")
+    with open(spec.ROOT / cfg0["file"]) as f:
+        newcfg = dict(json.load(f), name="newcfg", reference="newref")
+    (root / "bench/configs/newcfg.json").write_text(json.dumps(newcfg))
+    (root / "bench/references/newref.py").write_text(
+        "from harness.reference import BLOCK\n\n\n"
+        "def step_ops(rows, dims):\n    return BLOCK\n")
     (root / "bench/traffic/burst.json").write_text(json.dumps(
         {"loop": "open", "rate": 1.0, "ramp_s": 1,
          "prompt": {"median": 8, "sigma": 0.1, "min": 4, "max": 16},
@@ -73,6 +88,26 @@ def test_a_cell_added_as_data_resolves(tmp_path):
     assert "out_tok_s" not in [m["name"] for m in cell.end_to_end] or any(
         "newcfg.burst" in m.get("workloads", []) for m in cell.end_to_end)
     assert spec.metric_reader("new_metric", root)(None) == 42.0
+    assert cell.reference.step_ops([], None) == 512
+
+
+@pytest.mark.parametrize("named", [None, "no_such_reference"])
+def test_a_config_without_its_reference_is_an_error(named, tmp_path):
+    """A configuration that names no reference, or one with no file
+    behind it, fails as the cell is resolved, before any deploy."""
+    root = tmp_path
+    shutil.copytree(spec.BENCH, root / "bench",
+                    ignore=shutil.ignore_patterns(".state", "__pycache__"))
+    shutil.copy(spec.ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
+    w = spec.benchmark()["workloads"][0]
+    path = root / "bench" / "configs" / f"{w['config']}.json"
+    cfg = json.loads(path.read_text())
+    del cfg["reference"]
+    if named:
+        cfg["reference"] = named
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(spec.SpecError, match=named or "names no reference"):
+        spec.cell(w["name"], root)
 
 
 def test_unknown_names_are_errors(tmp_path):

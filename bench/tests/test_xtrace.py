@@ -74,6 +74,34 @@ def test_breakdown(tr):
     assert all(n.startswith(xtrace.LABELS) or n == "host" for n, _ in gaps)
 
 
+def _idle_gaps_by_scan(tr):
+    """`xtrace.idle_gaps` as a plain scan of every host span per gap."""
+    gaps, t = [], tr.t0
+    for a, b in xtrace.union(tr.ops[0]):
+        if a > t:
+            gaps.append((t, a))
+        t = max(t, b)
+    if tr.t1 > t:
+        gaps.append((t, tr.t1))
+    tot = {}
+    for a, b in gaps:
+        mid = (a + b) / 2
+        over = [s for s in tr.host if s[1] <= mid <= s[2]]
+        label = min(over, key=lambda s: s[2] - s[1])[0] if over else "host"
+        tot[label] = tot.get(label, 0.0) + (b - a)
+    return [[k, v] for k, v in sorted(tot.items(), key=lambda kv: -kv[1])]
+
+
+@pytest.mark.parametrize("name", ["window.xplane.pb",
+                                  "window_spans.xplane.pb"])
+def test_idle_gaps_sweep_equals_the_scan(name):
+    """The one sweep labels every gap as the scan of all host spans
+    does, to the last digit, on both recorded traces."""
+    tr = xtrace.load(os.path.join(os.path.dirname(TRACE), name))
+    assert tr.host
+    assert xtrace.idle_gaps(tr, n=100) == _idle_gaps_by_scan(tr)
+
+
 def test_step_programs(tr):
     steps = xtrace.module_spans(tr, "unified_step")
     assert steps and all(b > a for _, a, b in steps)
@@ -86,7 +114,6 @@ def test_decode_readers_on_the_recorded_trace(tr):
     import json
 
     from harness import measure, spec, window
-    from harness.weights import Dims
 
     with open(spec.BENCH / "configs" / "granite_3_2b_l1.json") as f:
         config = json.load(f)
@@ -96,9 +123,10 @@ def test_decode_readers_on_the_recorded_trace(tr):
                         chunks=[(0, 128)]) if i % 8 == 0 else
         window.Dispatch(t=0.0, width=1, decode=[1000] * 32, chunks=[])
         for i in range(n)])
+    ref = spec.config_reference(config)
     run = measure.Run(win, {}, tr.window_s, tr,
-                      Dims.from_config(config["model"]), config["engine"],
-                      spec.peaks("TPU v5 lite"), spec.counts)
+                      ref.Dims.from_config(config["model"]), config["engine"],
+                      spec.peaks("TPU v5 lite"), spec.counts, ref)
     cell = spec.cell("granite_3_2b_l1.decode_closed")
     got = {m["name"]: spec.metric_reader(m["name"])(run)
            for m in cell.per_layer}

@@ -8,6 +8,7 @@ from harness import spec
 
 CONFIG = {
     "name": "tiny_dense",
+    "reference": "dense_swiglu",
     "model": {"n_layers": 1, "d_model": 128, "n_heads": 4, "n_kv_heads": 2,
               "head_dim": 32, "d_ff": 256, "vocab": 256,
               "rope_theta": 10000.0, "rope_fraction": 1.0,
@@ -26,6 +27,8 @@ TRAFFIC = {"loop": "closed", "streams": 4, "start": "residual",
 # read on the CPU at this size over 13 seeds: the program's mean gap
 # 0.009-0.025, the four-bit control's 0.067-0.187
 LIMITS = {"mean_gap": 0.04}
+REFERENCE = spec.config_reference(CONFIG)  # loaded once: its programs
+# compile once per test process
 
 
 def cell(limits=None):
@@ -34,7 +37,7 @@ def cell(limits=None):
            if m["name"] in ("setup_s", "itl_p95_s", "out_tok_s")]
     return spec.Cell(
         name="tiny_dense.decode", chips=1, config=json.loads(
-            json.dumps(CONFIG)), traffic=dict(TRAFFIC),
+            json.dumps(CONFIG)), reference=REFERENCE, traffic=dict(TRAFFIC),
         check={"sample_requests": 8, "limits": dict(limits or LIMITS)},
         end_to_end=e2e, per_layer=[])
 
