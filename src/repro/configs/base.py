@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-from typing import Dict
+from typing import Dict, Optional, Tuple
 
 _REGISTRY: Dict[str, "ArchConfig"] = {}
 
@@ -16,8 +16,32 @@ ARCH_IDS = [
     "internvl2_76b", "falcon_mamba_7b", "olmoe_1b_7b",
     "llama4_maverick_400b_a17b", "granite_3_2b", "nemotron_4_340b",
     "llama3_2_3b", "chatglm3_6b", "zamba2_1_2b", "musicgen_medium",
-    "nemo_cnn",
+    "nemo_cnn", "mellum2_12b",
 ]
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (arXiv:2309.00071) as HF `rope_type: yarn`
+    states it: frequencies blended between interpolated and original
+    by a ramp over [beta_fast, beta_slow] rotations, and q.k scaled by
+    attention_factor squared."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """One layer's attention: `window` 0 is full causal; W > 0 lets the
+    query at position i see keys j with i - W < j <= i.  Optional YaRN
+    scaling of its layers' rotary frequencies (base: rope_base)."""
+
+    window: int = 0
+    yarn: Optional[Yarn] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,6 +61,9 @@ class ArchConfig:
     norm_bias: bool = False
     rope_base: float = 10000.0
     rope_fraction: float = 1.0
+    # one period of per-layer attention kinds, repeated over the layers;
+    # empty: every layer full causal
+    attn_pattern: Tuple[AttnKind, ...] = ()
     # --- MoE ---
     n_experts: int = 0
     top_k: int = 1
@@ -60,6 +87,11 @@ class ArchConfig:
     def hd(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
 
+    def attn_kind(self, layer: int) -> AttnKind:
+        if not self.attn_pattern:
+            return AttnKind()
+        return self.attn_pattern[layer % len(self.attn_pattern)]
+
     @property
     def vocab_padded(self) -> int:
         """Vocab padded to 256 so embedding/head shard cleanly on the
@@ -69,13 +101,19 @@ class ArchConfig:
 
     def reduced(self) -> "ArchConfig":
         """Tiny same-family variant for CPU smoke tests."""
-        kw = dataclasses.asdict(self)
+        kw = {f.name: getattr(self, f.name)
+              for f in dataclasses.fields(self)}
         kw.update(
             n_layers=min(
                 self.n_layers,
-                2 if self.shared_attn_every == 0
+                max(2, len(self.attn_pattern))
+                if self.shared_attn_every == 0
                 else self.shared_attn_every + 1,
             ),
+            # a window the reduced max_seq reaches
+            attn_pattern=tuple(
+                dataclasses.replace(k, window=min(k.window, 8))
+                for k in self.attn_pattern),
             d_model=128,
             vocab=256,
             d_ff=256 if self.d_ff else 0,

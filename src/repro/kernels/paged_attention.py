@@ -68,6 +68,14 @@ Masking contract (serving.cache.PagedArena layout):
     in, garbage out, exactly like the gather path: the engine never
     reads logits of inactive rows.
 
+Sliding window (`window` W, static per call): the query at position i
+sees keys j with i - W < j <= i.  The page loop of a row starts at the
+page holding its first query's oldest visible position (pages before
+it are neither copied nor multiplied: their logits lanes are filled
+with the mask value, so their probabilities are exactly 0), and older
+positions inside the pages walked take the mask.  `window=None` builds
+the kernel without either.
+
 GQA is folded into the page loads (kv head = h // group) — no
 head-expanded K/V copy exists anywhere.  `score_scale` may be a traced
 scalar (layer-stacked tables under lax.scan).
@@ -130,6 +138,7 @@ def _kernel(
     s_q: int,
     packed: bool,
     wide_dots: bool,
+    window=None,
 ):
     """One (slot b, head h) grid step.  q_ref/o_ref carry the head dim
     as (n_planes, S, hd / n_planes): one plane for int8 pools, the
@@ -155,10 +164,14 @@ def _kernel(
             pool.at[table_ref[b, j], kh], buf.at[slot], sem.at[slot]
         )
 
+    # the first page that holds a position some query row sees
+    lo = 0 if window is None else jnp.minimum(
+        jnp.maximum(pos_b - (window - 1), 0) // ps, pps - 1)
+
     def page_loop(pool, rq_ref, body, carry):
-        """body(j, planes, carry) over the table row's pages, page j + 1
-        copied while page j is used."""
-        copy(pool, 0, 0).start()
+        """body(j, planes, carry) over the table row's pages from `lo`,
+        page j + 1 copied while page j is used."""
+        copy(pool, lo, lo % 2).start()
 
         def step(j, carry):
             slot = j % 2
@@ -172,7 +185,7 @@ def _kernel(
             planes = _unpack_planes(blk, rq_ref, kh) if packed else [blk]
             return body(j, planes, carry)
 
-        return jax.lax.fori_loop(0, pps, step, carry)
+        return jax.lax.fori_loop(lo, pps, step, carry)
 
     def lanes(j):
         return pl.ds(pl.multiple_of(j * ps, ps), ps)
@@ -194,9 +207,19 @@ def _kernel(
         # query row s sits at position pos_b + s; causal mask per row
         q_pos = pos_b + jax.lax.broadcasted_iota(jnp.int32, (s_q, ps), 0)
         k_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (s_q, ps), 1)
-        logits_ref[:, lanes(j)] = lg + jnp.where(k_pos <= q_pos, 0.0, NEG_INF)
+        seen = k_pos <= q_pos
+        if window is not None:
+            seen = seen & (k_pos > q_pos - window)
+        logits_ref[:, lanes(j)] = lg + jnp.where(seen, 0.0, NEG_INF)
         return carry
 
+    if window is not None:
+        def unseen(j, carry):  # pages before the window: no copy, no dot
+            logits_ref[:, lanes(j)] = jnp.full((s_q, ps), NEG_INF,
+                                               jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, lo, unseen, 0)
     page_loop(k_hbm, k_rq, score, 0)
 
     # ---- float island: the model's global probability image ----
@@ -242,6 +265,7 @@ def paged_attention_pallas(
     group: int = 1,
     k_rq=None,
     v_rq=None,
+    window=None,
 ):
     """q (B, H, S, hd) int8 — S query rows per slot, row s at logical
     position pos[b] + s; k/v pools (n_pages + 1, K, hd, ps) int8;
@@ -254,7 +278,8 @@ def paged_attention_pallas(
     Int4-packed pools (DESIGN.md §Serving ¶Sub-8-bit KV) have a
     (hd/2, ps) trailing block; pass the per-kv-head unpack images as
     `k_rq`/`v_rq` (6, K) int32 operands and the kernel unpacks inside
-    the page loop.
+    the page loop.  `window` (static) limits each query to its last
+    `window` positions.
     """
     B, H, S, hd = q.shape
     n_pool, K, hd_store, ps = k_pool.shape
@@ -304,7 +329,7 @@ def paged_attention_pallas(
     def build(interpret):
         kern = functools.partial(
             _kernel, ps=ps, pps=pps, group=group, s_q=S, packed=packed,
-            wide_dots=interpret,
+            wide_dots=interpret, window=window,
         )
         return pl.pallas_call(
             kern,
@@ -334,6 +359,7 @@ def paged_attention(
     mesh=None,
     k_rq=None,
     v_rq=None,
+    window=None,
 ):
     """Mesh-aware dispatch for the fused paged attention (same contract
     as `paged_attention_pallas`, plus an optional serving mesh).
@@ -362,7 +388,7 @@ def paged_attention(
         return paged_attention_pallas(
             q, k_pool, v_pool, table, pos,
             score_scale=score_scale, group=group,
-            k_rq=k_rq, v_rq=v_rq,
+            k_rq=k_rq, v_rq=v_rq, window=window,
         )
     from jax.sharding import PartitionSpec as P
 
@@ -373,7 +399,7 @@ def paged_attention(
         return paged_attention_pallas(
             q_, k_, v_, tab_, pos_,
             score_scale=scale_, group=group,
-            k_rq=kr, v_rq=vr,
+            k_rq=kr, v_rq=vr, window=window,
         )
 
     in_specs = [

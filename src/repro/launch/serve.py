@@ -164,7 +164,7 @@ def main():
         "--prefill-chunk",
         type=int,
         default=32,
-        help="chunked-prefill chunk size (dense family); "
+        help="chunked-prefill chunk size (dense, moe); "
         "0 = whole-prompt bucketed prefill",
     )
     ap.add_argument(

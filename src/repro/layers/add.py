@@ -18,7 +18,7 @@ from typing import Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.requant import apply_rqt, make_rqt
+from repro.core.requant import make_rqt
 from repro.core.rep import Rep
 from repro.layers.common import ACT_QMAX, ACT_QMIN, DeployCtx
 
@@ -64,18 +64,37 @@ class QAdd:
         )
 
     def apply_id(self, t, s_a, s_b):
-        """Branches int8 (any zp) -> symmetric int8 sum (Eq. 24)."""
+        """Branches int8 (any zp) -> symmetric int8 sum (Eq. 24).
+
+        Each branch is requantized with both shifts rounding to nearest
+        (half up), not `apply_rqt`'s floor: a floor costs every Add
+        half a quantum of downward bias, which compounds along the
+        residual stream with depth."""
         qa = s_a.astype(jnp.int32) - t["zp_a"]
         qb = s_b.astype(jnp.int32) - t["zp_b"]
-        ya = apply_rqt(
-            qa, t["rq_a"], qmin=-(1 << 24), qmax=(1 << 24), out_dtype=jnp.int32
-        )
-        yb = apply_rqt(
-            qb, t["rq_b"], qmin=-(1 << 24), qmax=(1 << 24), out_dtype=jnp.int32
-        )
+        ya = _requant_round(qa, t["rq_a"])
+        yb = _requant_round(qb, t["rq_b"])
         return jnp.clip(ya + yb, ACT_QMIN, ACT_QMAX).astype(jnp.int8)
 
     def apply(self, t, a, b, rep, *, calib=None, scope=""):
         if rep is Rep.ID:
             return self.apply_id(t, a, b)
         return self.apply_fp(a, b, calib=calib, scope=scope)
+
+
+def shift_round(x, k):
+    """x >> k rounded to nearest, halves up: floor(x / 2^k + 1/2) for
+    int32 x and shifts k >= 0 (k may be traced).  Written as the floor
+    shift plus the bit below it, so the sum cannot overflow int32."""
+    k = jnp.asarray(k, jnp.int32)
+    below = jnp.right_shift(x, jnp.maximum(k - 1, 0)) & (k > 0)
+    return jnp.right_shift(x, k) + below
+
+
+def _requant_round(q, rqt):
+    """`core.requant.apply_rqt` into the int32 branch space of an Add,
+    with the pre-shift `s0` and the final shift `d - s0` rounded."""
+    m, d, s0 = rqt["m"], rqt["d"], rqt["s0"]
+    q = jnp.clip(q, rqt["lo"], rqt["hi"])
+    out = shift_round(shift_round(q, s0) * m, d - s0) + rqt["zp"]
+    return jnp.clip(out, -(1 << 24), 1 << 24)

@@ -40,6 +40,12 @@ neighboring slot's cache.  Their attention math still runs (garbage in,
 garbage out) but the engine reads logits only from rows whose final
 chunk completed.
 
+Sliding window (`window` W > 0, per layer kind — configs.base.AttnKind):
+the query at position i sees keys j with i - W < j <= i, in every path
+(the masks below, and the paged kernel's page walk and mask); YaRN
+rotary (`yarn`) scales q.k by its attention factor squared on the
+score scale, so the rope tables stay within [-1, 1].
+
 Paged KV (serving.cache.PagedArena): a decode cache dict may carry a
 per-slot page "table" (B, pages_per_slot) next to its pooled "k"/"v"
 leaves (n_pages + 1, K, hd, page_size) — position-minor, the layout
@@ -70,12 +76,13 @@ pinned token-for-token in tests/test_serving_sharded.py.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.configs.base import Yarn
 from repro.core.intmath import pack_int4, unpack_int4
 from repro.core.requant import apply_rqt, make_rqt
 from repro.core.rep import Rep
@@ -107,10 +114,25 @@ class QAttention:
     max_seq: int = 4096
     name: str = "attn"
     d_in: int = 0  # input width if != d_model (zamba2 shared block concat)
+    # sliding window: 0 is full causal; W > 0 lets the query at position
+    # i see keys j with i - W < j <= i (configs.base.AttnKind)
+    window: int = 0
+    yarn: Optional[Yarn] = None  # YaRN rotary scaling (layers/rope.py)
 
     @property
     def group(self) -> int:
         return self.n_heads // self.n_kv_heads
+
+    def _rope_tables(self, make):
+        return make(self.head_dim, self.max_seq, self.rope_base,
+                    self.rope_fraction, self.yarn)
+
+    def _yarn_gain(self, scores):
+        """YaRN's attention factor scales q and k alike, so q.k by its
+        square (HF scales cos and sin)."""
+        if self.yarn is None:
+            return scores
+        return scores * np.float32(self.yarn.attention_factor ** 2)
 
     def _sub(self):
         H, K, hd = self.n_heads, self.n_kv_heads, self.head_dim
@@ -162,9 +184,7 @@ class QAttention:
         q, k, v = self._shape_qkv(q, k, v, B, S)
         if S > 1:  # decode: q stays unhinted so GSPMD follows the
             q = hint(q, "act_bhsd")  # sequence-sharded cache layout
-        rot, cos, sin = rope_tables_fp(
-            hd, self.max_seq, self.rope_base, self.rope_fraction
-        )
+        rot, cos, sin = self._rope_tables(rope_tables_fp)
         positions = _positions(S, pos)
         q = apply_rope_fp(q, cos, sin, positions, rot)
         k = apply_rope_fp(k, cos, sin, positions, rot)
@@ -199,8 +219,8 @@ class QAttention:
             self._expand_kv(v), "act_bhsd")
         scores = jnp.einsum("bhsd,bhtd->bhst", q, kh,
                             preferred_element_type=jnp.float32)
-        scores = scores / np.sqrt(hd)
-        scores = scores + _mask(S, T, pos)
+        scores = self._yarn_gain(scores / np.sqrt(hd))
+        scores = scores + _mask(S, T, pos, self.window)
         probs = hint(
             jax.nn.softmax(scores, axis=-1), "probs_dec" if S == 1 else "probs"
         )
@@ -249,6 +269,8 @@ class QAttention:
             eps[short] = a_eps
         # island scale: int32 scores * eps_q*eps_k/sqrt(hd) -> f32 logits
         eps_s = eps["q"] * eps["k"] / np.sqrt(self.head_dim)
+        if self.yarn is not None:
+            eps_s = eps_s * self.yarn.attention_factor ** 2
         t["score_scale"] = np.float32(eps_s)
         # integer-softmax tables (attn_softmax=int variant; all-int32)
         from repro.core.intsoftmax import make_int_softmax_tables
@@ -327,8 +349,7 @@ class QAttention:
         q, k, v = self._shape_qkv(q, k, v, B, S)
         if S > 1:
             q = hint(q, "act_bhsd")
-        rot, cos_q, sin_q = rope_tables_int(hd, self.max_seq, self.rope_base,
-                                            self.rope_fraction)
+        rot, cos_q, sin_q = self._rope_tables(rope_tables_int)
         positions = _positions(S, pos)
         q = apply_rope_int(q, cos_q, sin_q, positions, rot)
         k = apply_rope_int(k, cos_q, sin_q, positions, rot)
@@ -387,7 +408,7 @@ class QAttention:
                 # integer-only softmax: NO float island at all
                 from repro.core.intsoftmax import int_softmax
 
-                bmask = _bool_mask(S, T, pos)
+                bmask = _bool_mask(S, T, pos, self.window)
                 s_p = hint(
                     int_softmax(scores, t["sm_tabs"], mask=bmask),
                     "probs_dec" if S == 1 else "probs",
@@ -395,7 +416,7 @@ class QAttention:
             else:
                 # ---- float island (paper §3.8: exponentials) ----
                 logits = scores.astype(jnp.float32) * t["score_scale"]
-                logits = logits + _mask(S, T, pos)
+                logits = logits + _mask(S, T, pos, self.window)
                 probs = hint(
                     jax.nn.softmax(logits, axis=-1),
                     "probs_dec" if S == 1 else "probs",
@@ -436,9 +457,10 @@ class QAttention:
             logits = s.astype(jnp.float32) * t["score_scale"]
             k_pos = j * blk + jnp.arange(blk)
             if q_pos.ndim == 2:  # per-slot positions -> (B,1,S,blk)
-                mask = k_pos[None, None, None, :] <= q_pos[:, None, :, None]
+                k_b, q_b = k_pos[None, None, None, :], q_pos[:, None, :, None]
             else:
-                mask = k_pos[None, :] <= q_pos[:, None]
+                k_b, q_b = k_pos[None, :], q_pos[:, None]
+            mask = _visible(k_b, q_b, self.window)
             logits = jnp.where(mask, logits, NEG_INF)
             m_new = jnp.maximum(m_run, jnp.max(logits, axis=-1))
             p = jnp.exp(logits - m_new[..., None])
@@ -497,7 +519,7 @@ class QAttention:
         acc = paged_attention(
             q, cache["k"], cache["v"], cache["table"], pos_v,
             score_scale=t["score_scale"], group=self.group,
-            mesh=profile_mesh(), **kw)
+            mesh=profile_mesh(), window=self.window or None, **kw)
         s_ctx = apply_rqt(acc, t["ctx_rqt"])
         B, _, S, _ = q.shape
         s_ctx = s_ctx.transpose(0, 2, 1, 3)
@@ -723,8 +745,18 @@ def _cache_write(cache, new, pos):
     return jax.lax.dynamic_update_slice_in_dim(cache, new, pos, axis=2)
 
 
-def _bool_mask(S: int, T: int, pos):
-    """Causal keep-mask as booleans (integer-softmax island).
+def _visible(j, i, window: int = 0):
+    """Key position j visible to the query at position i: causal, and
+    within the last `window` positions when window > 0."""
+    keep = j <= i
+    if window:
+        keep = keep & (j > i - window)
+    return keep
+
+
+def _bool_mask(S: int, T: int, pos, window: int = 0):
+    """Causal (and windowed) keep-mask as booleans (integer-softmax
+    island).
 
     (S, T) for shared positions; (B, 1, S, T) for per-slot `pos` (B,) —
     broadcasts against (B, H, S, T) scores either way.
@@ -732,10 +764,11 @@ def _bool_mask(S: int, T: int, pos):
     i = _positions(S, pos)
     j = jnp.arange(T)
     if i.ndim == 2:
-        return j[None, None, None, :] <= i[:, None, :, None]
-    return j[None, :] <= i[:, None]
+        return _visible(j[None, None, None, :], i[:, None, :, None], window)
+    return _visible(j[None, :], i[:, None], window)
 
 
-def _mask(S: int, T: int, pos):
+def _mask(S: int, T: int, pos, window: int = 0):
     """Causal (prefill) or length (decode) mask, f32 (island-side)."""
-    return jnp.where(_bool_mask(S, T, pos), 0.0, NEG_INF).astype(jnp.float32)
+    return jnp.where(_bool_mask(S, T, pos, window), 0.0,
+                     NEG_INF).astype(jnp.float32)

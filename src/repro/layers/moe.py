@@ -1,14 +1,19 @@
-"""Mixture-of-Experts with gather/scatter dispatch (EP-shardable).
+"""Mixture-of-Experts: capacity routing for training, dropless grouped
+experts for the integer (served) path.
 
-Dispatch is *index-based* (argsort-free slotting via one-hot cumsum +
-scatter-drop, then gathers), so the lowered HLO carries no mostly-zero
-dispatch einsums — compiled FLOPs stay equal to useful FLOPs, which keeps
+Float path (training, calibration): dispatch is *index-based*
+(argsort-free slotting via one-hot cumsum + scatter-drop, then
+gathers), so the lowered HLO carries no mostly-zero dispatch einsums — compiled FLOPs stay equal to useful FLOPs, which keeps
 the §Roofline MODEL_FLOPS/HLO_FLOPs ratio honest.  Capacity-dropped
 tokens lose those expert contributions (their gate mass is simply absent
 from the combine — standard Switch semantics).
 
-ID lowering: router logits are an int32 accumulator; softmax/top-k is a
-float island (paper §3.8 — it is an exponential) whose output gates are
+ID lowering (`apply_id`, the served path): dropless — every routed
+(token, expert) pair runs, grouped by expert, in one Pallas kernel per
+layer and call (kernels/moe_experts.py), and tokens the caller marks
+invalid route nowhere, so no token's output depends on another's.
+Router logits are an int32 accumulator; softmax/top-k is a float
+island (paper §3.8 — it is an exponential) whose output gates are
 requantized to int8 images (eps = 1/127, zp = 0, like attention probs).
 Expert FFNs are per-expert W8A8 with shared activation spaces across
 experts (per-expert per-channel weight quanta), so the SiLU LUT and all
@@ -23,8 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.intmath import apply_lut, build_lut
-from repro.core.requant import apply_rqt, make_rqt
+from repro.core.intmath import build_lut
+from repro.core.requant import make_rqt
 from repro.core.rep import Rep
 from repro.layers.common import (
     ACT_QMIN, ActKind, DeployCtx, act_fn, act_fn_np,
@@ -112,26 +117,6 @@ class QMoE:
         def one(he, e_row, p_row):
             return he[e_row, p_row]  # (Gs,k,f)
         return jax.vmap(one)(he_pad, experts, pos)
-
-    @staticmethod
-    def _combine_sum(he_pad, experts, pos, weights, out_dtype):
-        """Loop-over-k combine: y = sum_i w_i * he[e_i, p_i] without ever
-        materializing the (G,Gs,k,d) tensor (k x less live memory)."""
-        G, Gs, k = experts.shape
-        d = he_pad.shape[-1]
-
-        def body(i, acc):
-            e_i = jax.lax.dynamic_index_in_dim(experts, i, 2, keepdims=False)
-            p_i = jax.lax.dynamic_index_in_dim(pos, i, 2, keepdims=False)
-            w_i = jax.lax.dynamic_index_in_dim(weights, i, 2, keepdims=True)
-
-            def one(he, e_row, p_row):
-                return he[e_row, p_row]  # (Gs, d)
-            yk = jax.vmap(one)(he_pad, e_i, p_i)
-            return acc + yk.astype(out_dtype) * w_i.astype(out_dtype)
-
-        acc0 = jnp.zeros((G, Gs, d), out_dtype)
-        return jax.lax.fori_loop(0, k, body, acc0)
 
     def aux_loss(self, logits_f, experts):
         """Switch-style load-balance loss (mean prob * assignment frac)."""
@@ -288,51 +273,35 @@ class QMoE:
         return t, np.asarray([eps_comb])  # layer-wise acc quantum
 
     # -- integer path ---------------------------------------------------------
-    def apply_id(self, t, s_x):
-        """s_x (T, d) int8 -> int32 accumulator (T, d) in eps_comb units."""
-        xg, gs = self._group(s_x)
-        G = xg.shape[0]
-        r_acc = self._router().apply_id(t["router"], xg)
-        # ---- float island: softmax + top-k on tiny (G,Gs,E) ----
-        logits = r_acc.astype(jnp.float32) * t["router_scale"]
-        gates, experts, pos, tfs, C = self._route(logits)
-        s_gates = jnp.round(gates * 127.0).astype(jnp.int8)
-        # ---- island exit ----
-        from repro.sharding.hints import hint
+    def apply_id(self, t, s_x, valid=None):
+        """s_x (T, d) int8 -> int32 accumulator (T, d) in eps_comb units.
 
-        x_pad = jnp.concatenate([xg, jnp.zeros_like(xg[:, :1])], axis=1)
-        xe = hint(self._gather_tokens(x_pad, tfs), "moe_ecd")  # (G,E,C,d)
-        acc_g = jnp.einsum(
-            "gecd,edf->gecf",
-            xe.astype(jnp.int8),
-            t["wg_q"],
-            preferred_element_type=jnp.int32,
-        )
-        acc_u = jnp.einsum(
-            "gecd,edf->gecf",
-            xe.astype(jnp.int8),
-            t["wu_q"],
-            preferred_element_type=jnp.int32,
-        )
-        s_pre = apply_rqt(acc_g, _expand(t["g_rqt"], 1))
-        s_g = apply_lut(s_pre, t["g_lut"])
-        s_u = apply_rqt(acc_u, _expand(t["u_rqt"], 1))
-        prod = (s_g.astype(jnp.int32) - t["zp_g"]) * s_u.astype(jnp.int32)
-        s_h = apply_rqt(prod, t["h_rqt"])
-        acc_o = jnp.einsum(
-            "gecf,efd->gecd",
-            s_h.astype(jnp.int8),
-            t["wd_q"],
-            preferred_element_type=jnp.int32,
-        )
-        s_o = apply_rqt(acc_o, _expand(t["o_rqt"], 1))  # (G,E,C,d) int8
-        o_pad = jnp.concatenate([s_o, jnp.zeros_like(s_o[:, :, :1])], axis=2)
-        pos_safe = jnp.where(s_gates > 0, pos, C)
-        yk = self._combine(o_pad, experts, pos_safe, gates)     # int8
-        acc = jnp.sum(
-            yk.astype(jnp.int32) * s_gates[..., None].astype(jnp.int32),
-            axis=2)
-        return acc.reshape(s_x.shape[0], -1)
+        Dropless: every token's top-k experts are computed, with no
+        capacity, so a token's output depends on that token alone.
+        `valid` (T,) bool: tokens marked False (chunk padding, rows the
+        serving engine parks) route nowhere and get a zero output.  The
+        kernel computes nothing for their pairs, but the sort, the row
+        gathers and the gate-weighted sum still run over all T * k."""
+        from repro.kernels.moe_experts import moe_experts
+
+        with jax.named_scope("moe/router"):
+            r_acc = self._router().apply_id(t["router"], s_x)
+            # ---- float island: softmax + top-k on tiny (T, E) ----
+            logits = r_acc.astype(jnp.float32) * t["router_scale"]
+            probs = jax.nn.softmax(logits, axis=-1)
+            gates, experts = jax.lax.top_k(probs, self.top_k)  # (T, k)
+            if self.normalize_gates:
+                gates = gates / jnp.maximum(
+                    jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+            s_gates = jnp.round(gates * 127.0).astype(jnp.int32)
+            # ---- island exit ----
+            if valid is not None:
+                experts = jnp.where(valid[:, None], experts, self.n_experts)
+                s_gates = jnp.where(valid[:, None], s_gates, 0)
+        with jax.named_scope("moe/experts"):
+            s_o = moe_experts(s_x, experts, t)  # (T, k, d) int8
+            return jnp.sum(s_o.astype(jnp.int32) * s_gates[..., None],
+                           axis=1)
 
     def apply(self, p, x, rep, *, qs=None, calib=None, scope=""):
         if rep is Rep.ID:
@@ -359,9 +328,3 @@ def _fq_w3(w, beta):
     mask = jnp.logical_and(w >= -b, w < b).astype(w.dtype)
     return jax.lax.stop_gradient(q - mask * w) + mask * w
 
-
-def _expand(rqt: dict, extra_axis: int) -> dict:
-    """Expert-wise rqt tables (E, C_out) -> (E, 1, C_out), broadcastable
-    over the slot axis of a (G, E, C, C_out) accumulator."""
-    return {k: (v[:, None, :] if getattr(v, "ndim", 0) == 2 else v)
-            for k, v in rqt.items()}

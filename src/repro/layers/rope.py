@@ -13,10 +13,17 @@ only the floor).  Rotations preserve norm, so eps is unchanged.
 
 ``fraction`` < 1 rotates only the leading channels (chatglm3's 2d RoPE
 applies rotary to half the head dim).
+
+``yarn`` (configs.base.Yarn) blends each frequency between its original
+and its interpolated (divided by the factor) value, as YaRN does, with
+the blend computed here at deploy time; the table values stay cos/sin,
+and YaRN's attention factor is the attention layer's to apply (on the
+score scale), so every table entry stays within [-1, 1].
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax.numpy as jnp
 import numpy as np
@@ -24,26 +31,49 @@ import numpy as np
 TRIG_BITS = 14
 
 
+def inv_freq(rot: int, base: float, yarn=None) -> np.ndarray:
+    """(rot / 2,) float64 inverse frequencies; YaRN-blended with `yarn`
+    (HF `_compute_yarn_parameters`, truncated correction range)."""
+    inv = 1.0 / (base ** (np.arange(0, rot, 2, dtype=np.float64) / rot))
+    if yarn is None:
+        return inv
+
+    def dim_of(n_rot):  # the channel pair that turns n_rot times
+        return (rot * math.log(yarn.original_max_position
+                               / (n_rot * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), rot - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rot // 2) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp  # share of the original (extrapolated) frequency
+    return inv / yarn.factor * (1.0 - keep) + inv * keep
+
+
 @functools.lru_cache(maxsize=32)
-def _angles(head_dim: int, max_pos: int, base: float, fraction: float):
+def _angles(head_dim: int, max_pos: int, base: float, fraction: float,
+            yarn=None):
     rot = int(head_dim * fraction)
     rot -= rot % 2
-    inv = 1.0 / (base ** (np.arange(0, rot, 2, dtype=np.float64) / rot))
+    inv = inv_freq(rot, base, yarn)
     pos = np.arange(max_pos, dtype=np.float64)
     ang = np.outer(pos, inv)  # (S, rot/2)
     return rot, np.cos(ang), np.sin(ang)
 
 
 def rope_tables_fp(
-    head_dim: int, max_pos: int, base: float = 10000.0, fraction: float = 1.0
+    head_dim: int, max_pos: int, base: float = 10000.0, fraction: float = 1.0,
+    yarn=None,
 ):
-    rot, cos, sin = _angles(head_dim, max_pos, base, fraction)
+    rot, cos, sin = _angles(head_dim, max_pos, base, fraction, yarn)
     return rot, jnp.asarray(cos, jnp.float32), jnp.asarray(sin, jnp.float32)
 
 
 def rope_tables_int(head_dim: int, max_pos: int, base: float = 10000.0,
-                    fraction: float = 1.0):
-    rot, cos, sin = _angles(head_dim, max_pos, base, fraction)
+                    fraction: float = 1.0, yarn=None):
+    rot, cos, sin = _angles(head_dim, max_pos, base, fraction, yarn)
     scale = float(1 << TRIG_BITS)
 
     def enc(v):

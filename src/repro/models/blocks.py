@@ -15,11 +15,12 @@ handles both cache layouts transparently).
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.configs.base import Yarn
 from repro.layers.add import QAdd
 from repro.layers.attention import QAttention
 from repro.layers.common import ActKind, DeployCtx
@@ -50,6 +51,9 @@ class DenseBlock:
     top_k: int = 1
     moe_group: int = 512
     shared_expert: bool = False
+    # attention kind (configs.base.AttnKind): sliding window, YaRN
+    window: int = 0
+    yarn: Optional[Yarn] = None
 
     def _subs(self):
         s = {
@@ -65,6 +69,8 @@ class DenseBlock:
                 rope_base=self.rope_base,
                 rope_fraction=self.rope_fraction,
                 max_seq=self.max_seq,
+                window=self.window,
+                yarn=self.yarn,
             ),
             "add1": QAdd(name="add1"),
             "norm2": QNorm(
@@ -204,7 +210,9 @@ class DenseBlock:
         return t, eps_r2
 
     # -- integer --------------------------------------------------------------
-    def apply_id(self, t, s_x, *, cache=None, pos=None):
+    def apply_id(self, t, s_x, *, cache=None, pos=None, valid=None):
+        """`valid` (B, S) bool marks the real tokens; the MoE routes the
+        others nowhere (None: every token is real)."""
         from repro.core.requant import apply_rqt
         from repro.sharding.hints import hint
 
@@ -218,7 +226,9 @@ class DenseBlock:
         h = subs["norm2"].apply_id(t["norm2"], s_r)
         if self.n_experts > 0:
             B, S, D = h.shape
-            m_acc = subs["moe"].apply_id(t["moe"], h.reshape(B * S, D))
+            m_acc = subs["moe"].apply_id(
+                t["moe"], h.reshape(B * S, D),
+                None if valid is None else valid.reshape(B * S))
             m_acc = m_acc.reshape(B, S, D)
             if self.shared_expert:
                 sh_acc = subs["mlp"].apply_id(t["mlp"], h)

@@ -9,6 +9,10 @@ Layer stacks are lax.scan'ed over stacked parameters (bounds HLO size at
   ssm                  : [scan(MambaBlock) x L]
   hybrid (zamba2)      : [scan(group: k x Mamba2 + shared-attn) x G, tail]
 
+A dense or moe_every = 1 model whose layers mix attention kinds
+(`ArchConfig.attn_pattern`: sliding window, YaRN) scans each run of
+consecutive layers of one kind as a segment of its own.
+
 Lifecycle: init (FP) -> calibrate (FP, eager per-layer scopes) ->
 deploy (host, per-layer tables -> stacked) -> ID apply (scan over tables).
 FQ uses the same apply with rep=Rep.FQ + a qstate pytree (PACT clips).
@@ -25,6 +29,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.calibrate import Calibrator
 from repro.core.rep import Rep
+from repro.layers.attention import INACTIVE_POS
 from repro.layers.common import ActKind, DeployCtx, stack_trees
 from repro.layers.embedding import QEmbed
 from repro.layers.linear import QLinear
@@ -51,8 +56,9 @@ class DecoderLM:
     # ------------------------------------------------------------------
     # segment plan
     # ------------------------------------------------------------------
-    def _dense_tpl(self, moe: bool) -> DenseBlock:
+    def _dense_tpl(self, moe: bool, kind=None) -> DenseBlock:
         c = self.cfg
+        kind = kind or c.attn_kind(0)
         return DenseBlock(
             d_model=c.d_model, n_heads=c.n_heads, n_kv_heads=c.n_kv_heads,
             head_dim=c.hd, d_ff=c.d_ff, act=ACT_MAP[c.act], gated=c.gated,
@@ -60,6 +66,7 @@ class DecoderLM:
             rope_fraction=c.rope_fraction, max_seq=self.max_seq,
             n_experts=(c.n_experts if moe else 0), top_k=c.top_k,
             moe_group=c.moe_group, shared_expert=(c.shared_expert and moe),
+            window=kind.window, yarn=kind.yarn,
         )
 
     def _mamba_tpl(self) -> MambaBlock:
@@ -84,15 +91,29 @@ class DecoderLM:
             norm=c.norm,
         )
 
+    def _runs(self, moe: bool):
+        """One "dense" segment per run of consecutive layers that share
+        an attention kind (configs.base.AttnKind): a sliding-window
+        layer and a full one are different templates."""
+        segs = []
+        for i in range(self.cfg.n_layers):
+            kind = self.cfg.attn_kind(i)
+            if segs and segs[-1][0] == kind:
+                segs[-1][1] += 1
+            else:
+                segs.append([kind, 1])
+        return [("dense", self._dense_tpl(moe, kind), n)
+                for kind, n in segs]
+
     def plan(self):
         """-> list of segments: (kind, template(s), n_steps)."""
         c = self.cfg
         if c.family == "dense" or (
             c.family == "moe" and c.moe_every == 1 and c.n_experts == 0
         ):
-            return [("dense", self._dense_tpl(False), c.n_layers)]
+            return self._runs(False)
         if c.family == "moe" and c.moe_every == 1:
-            return [("dense", self._dense_tpl(True), c.n_layers)]
+            return self._runs(True)
         if c.family == "moe" and c.moe_every == 2:
             assert c.n_layers % 2 == 0
             pair = (self._dense_tpl(False), self._dense_tpl(True))
@@ -126,7 +147,9 @@ class DecoderLM:
         segs = []
         kidx = 3
         for si, (kind, tpl, n) in enumerate(self.plan()):
-            layer_keys = jax.random.split(keys[min(kidx + si, 7)], n)
+            seg_key = (keys[kidx + si] if kidx + si < 7
+                       else jax.random.fold_in(keys[7], si))
+            layer_keys = jax.random.split(seg_key, n)
             if kind in ("dense", "mamba"):
                 stacked = jax.vmap(tpl.init)(layer_keys)
             elif kind == "pair":
@@ -180,9 +203,13 @@ class DecoderLM:
         return batch  # embeds provided by the (stubbed) modality frontend
 
     def apply(
-        self, p, x, rep, *, qstate=None, caches=None, pos=None, calib=None
+        self, p, x, rep, *, qstate=None, caches=None, pos=None, calib=None,
+        valid=None,
     ):
-        """x: embedded input (B,S,d) float. -> (hidden, caches, aux_sum)"""
+        """x: embedded input (B,S,d) float. -> (hidden, caches, aux_sum)
+
+        valid (B, S) bool (ID only): the real tokens of a serving
+        dispatch; MoE layers route the others nowhere."""
         c = self.cfg
         aux_total = jnp.float32(0.0)
         new_caches = []
@@ -204,7 +231,7 @@ class DecoderLM:
             else:
                 x, caches_i, aux = self._seg_scan(
                     kind, tpl, seg_p, seg_qs, x, x0, rep,
-                    caches[ci] if caches else None, pos, p)
+                    caches[ci] if caches else None, pos, p, valid)
             aux_total = aux_total + aux
             new_caches.append(caches_i)
             ci += 1
@@ -285,7 +312,8 @@ class DecoderLM:
         return x, caches_out, aux_total
 
     def _seg_scan(
-        self, kind, tpl, seg_p, seg_qs, x, x0, rep, caches, pos, p_root
+        self, kind, tpl, seg_p, seg_qs, x, x0, rep, caches, pos, p_root,
+        valid=None,
     ):
         """lax.scan over stacked layer params (jit path)."""
         c = self.cfg
@@ -296,7 +324,8 @@ class DecoderLM:
                 h, aux = carry
                 lp, lqs, lc = xs
                 if rep is Rep.ID:
-                    h2, lc2 = tpl.apply_id(lp, h, cache=lc, pos=pos)
+                    kw = {"valid": valid} if kind == "dense" else {}
+                    h2, lc2 = tpl.apply_id(lp, h, cache=lc, pos=pos, **kw)
                     a2 = aux
                 else:
                     h2, lc2, a = tpl.apply_float(
@@ -324,8 +353,10 @@ class DecoderLM:
                 ca = _tree_slice(lc, 0) if lc is not None else None
                 cb = _tree_slice(lc, 1) if lc is not None else None
                 if rep is Rep.ID:
-                    h, ca2 = a_tpl.apply_id(lp["a"], h, cache=ca, pos=pos)
-                    h, cb2 = b_tpl.apply_id(lp["b"], h, cache=cb, pos=pos)
+                    h, ca2 = a_tpl.apply_id(lp["a"], h, cache=ca, pos=pos,
+                                            valid=valid)
+                    h, cb2 = b_tpl.apply_id(lp["b"], h, cache=cb, pos=pos,
+                                            valid=valid)
                     a_sum = aux
                 else:
                     h, ca2, _ = a_tpl.apply_float(
@@ -572,7 +603,12 @@ class DecoderLM:
         engine ignores the rest.
         """
         x = self.embed_in_id(t, batch)
-        x, caches, _ = self.apply(t, x, Rep.ID, caches=caches, pos=start_pos)
+        # real tokens: a row's columns up to its last_index, unless the
+        # row is parked (decode rows are width-1 chunks, last_index 0)
+        valid = ((start_pos < INACTIVE_POS)[:, None]
+                 & (jnp.arange(batch.shape[1]) <= last_index[:, None]))
+        x, caches, _ = self.apply(t, x, Rep.ID, caches=caches, pos=start_pos,
+                                  valid=valid)
         idx = jnp.broadcast_to(
             last_index[:, None, None], (x.shape[0], 1, x.shape[-1]))
         h = jnp.take_along_axis(x, idx, axis=1)

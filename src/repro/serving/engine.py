@@ -45,12 +45,14 @@ whole-prompt prefill + fused decode dispatches — they are the parity
 oracles for the chunked path, not hot paths.
 
 The prefill dispatch decision is made in ONE place (_prefill_mode):
-"chunked" (dense family, prefill_chunk > 0 — the default), "bucketed"
-(dense, chunking disabled: whole prompt at bucket-padded length, B=1 —
-kept as the token-parity oracle for the chunked path), or "exact"
-(ssm/moe/hybrid: whole prompt at exact length — MoE capacity routing
-and SSM/hybrid recurrences integrate every position, so neither
-padding nor garbage chunk rows are admissible; DESIGN.md §Serving).
+"chunked" (dense and moe families, prefill_chunk > 0 — the default),
+"bucketed" (chunking disabled: whole prompt at bucket-padded length,
+B=1 — kept as the token-parity oracle for the chunked path), or
+"exact" (ssm/hybrid: whole prompt at exact length — their recurrences
+integrate every position, so neither padding nor garbage chunk rows
+are admissible; DESIGN.md §Serving).  MoE layers route dropless on the
+integer path and route padding and parked rows nowhere, so their rows
+are as independent as a dense model's.
 
 Greedy sampling is argmax on int32 logits — no dequantization anywhere
 (the paper's integer-only deployment invariant; asserted on the cache
@@ -82,12 +84,9 @@ same output as the synchronous engine for row-independent families
 parity tests pin; request *timing* may shift by a step (admission
 sees slot releases one harvest later).
 
-Decode rows of free slots compute garbage that is never read; for pure
-dense/ssm/hybrid families rows are independent so active slots are
-bit-exact with the lockstep path.  MoE capacity routing couples rows
-(a garbage row can compete for expert capacity) — see DESIGN.md
-§Serving for the caveat (under async dispatch the same caveat covers
-the one-step admission shift).
+Decode rows of free slots compute garbage that is never read; rows
+are independent in every family (MoE routing on the integer path is
+dropless), so active slots are bit-exact with the lockstep path.
 
 Telemetry (`telemetry=`, DESIGN.md §Observability): the engine threads
 an off-by-default, bit-neutral observability sink through every
@@ -370,21 +369,18 @@ class ServingEngine:
                 out_shardings=(repl, dv_sh),
             )
         # THE prefill dispatch decision (single place; see module doc):
-        #   chunked  — dense, prefill_chunk > 0: packed fixed-shape
-        #              chunk dispatch straight into the arena
-        #   bucketed — dense, chunking disabled: whole prompt at
-        #              bucket-padded length, B=1 (the parity oracle).
-        #              Padding is exact only when rows/positions are
-        #              causally independent: attention masks padded
-        #              positions.
-        #   exact    — MoE capacity routing mixes tokens (padded or
-        #              garbage tokens would compete for expert
-        #              capacity) and SSM/hybrid recurrent conv/scan
-        #              state integrates every prefilled position —
-        #              those families prefill the whole prompt at
-        #              exact length (one compile per distinct length).
-        #              DESIGN.md §Serving.
-        if lm.cfg.family != "dense":
+        #   chunked  — dense and moe, prefill_chunk > 0: packed
+        #              fixed-shape chunk dispatch straight into the arena
+        #   bucketed — chunking disabled: whole prompt at bucket-padded
+        #              length, B=1 (the parity oracle).  Padding is
+        #              exact only when rows/positions are causally
+        #              independent: attention masks padded positions,
+        #              and dropless MoE routing is per token.
+        #   exact    — SSM/hybrid recurrent conv/scan state integrates
+        #              every prefilled position — those families
+        #              prefill the whole prompt at exact length (one
+        #              compile per distinct length).  DESIGN.md §Serving.
+        if lm.cfg.family in ("ssm", "hybrid"):
             self._prefill_mode = "exact"
         elif self.sched.cfg.prefill_chunk > 0:
             self._prefill_mode = "chunked"
@@ -408,14 +404,16 @@ class ServingEngine:
         if cfg.kv_bits == 4 and self._prefill_mode != "chunked":
             raise ValueError(
                 "kv_bits=4 requires the chunked prefill path "
-                f"(prefill_chunk > 0, dense family); this engine is in "
+                f"(prefill_chunk > 0, dense or moe family); this engine "
+                f"is in "
                 f"{self._prefill_mode!r} mode"
             )
         self._prefix_on = bool(cfg.prefix_cache)
         if self._prefix_on and self._prefill_mode != "chunked":
             raise ValueError(
                 "prefix_cache=True requires the chunked prefill path "
-                f"(prefill_chunk > 0, dense family); this engine is in "
+                f"(prefill_chunk > 0, dense or moe family); this engine "
+                f"is in "
                 f"{self._prefill_mode!r} mode"
             )
         if self._prefix_on:

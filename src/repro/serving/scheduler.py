@@ -22,7 +22,7 @@ preemption-free.  Iteration: every leased slot advances one token
 through a single fused decode step with a per-slot position vector;
 completed slots are recycled the same step.
 
-Chunked prefill (`prefill_chunk` > 0, dense family): admission only
+Chunked prefill (`prefill_chunk` > 0, dense and moe): admission only
 leases a slot; the prompt then enters the arena `prefill_chunk` tokens
 at a time through a *packed* compact dispatch — one (row-bucket,
 chunk) prefill per engine step carrying the next chunk of every
@@ -71,7 +71,7 @@ from repro.serving.request import PrefillState, Request
 class SchedulerConfig:
     max_prefills_per_step: int = 2  # admission cap per engine step
     prefill_bucket: int = 16  # prompt-shape bucket (compile bound)
-    # chunked prefill (dense family): tokens per chunk; 0 falls back to
+    # chunked prefill (dense, moe): tokens per chunk; 0 falls back to
     # the whole-prompt bucketed path (the parity oracle)
     prefill_chunk: int = 32
     # fairness knob: chunk rows packed per dispatch (None: every

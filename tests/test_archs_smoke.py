@@ -57,7 +57,8 @@ def test_reduced_fp_forward_shapes(arch):
 
 @pytest.mark.parametrize("arch", ["granite_3_2b", "olmoe_1b_7b",
                                   "falcon_mamba_7b", "zamba2_1_2b",
-                                  "chatglm3_6b", "musicgen_medium"])
+                                  "chatglm3_6b", "musicgen_medium",
+                                  "mellum2_12b"])
 def test_reduced_id_serve(arch):
     """calibrate -> deploy -> integer prefill + decode; int32 logits."""
     cfg = get_config(arch).reduced()

@@ -13,7 +13,7 @@ from repro.models.lm import DecoderLM
 
 FAMILIES = ["granite_3_2b", "olmoe_1b_7b", "falcon_mamba_7b",
             "llama4_maverick_400b_a17b", "zamba2_1_2b", "nemotron_4_340b",
-            "musicgen_medium"]
+            "musicgen_medium", "mellum2_12b"]
 
 
 @pytest.mark.parametrize("arch", FAMILIES)

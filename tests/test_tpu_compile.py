@@ -1,10 +1,13 @@
-"""Compile the paged kernel and the table lookup for a described TPU v5e.
+"""Compile the kernels and the table lookup for a described TPU v5e.
 
-Nothing runs here: each test lowers the kernel at granite_3_2b's
-attention widths (32 query heads, 8 kv heads, head_dim 64) for one chip
-of a v5e topology and compiles it with the TPU compiler, which refuses
-what the Pallas interpreter accepts — tiles not aligned to the
-hardware, more VMEM than a kernel may use.  The HLO must hold the
+Nothing runs here: each test lowers a kernel at granite_3_2b's
+attention widths (32 query heads, 8 kv heads, head_dim 64), or the
+windowed paged kernel and the grouped expert kernel at
+mellum2_12b's (32 query heads over 4 kv heads of 128, window 1024; 64
+experts of width 896 over d_model 2304, top-8), for one chip of a v5e
+topology and compiles it with the TPU compiler, which refuses what the
+Pallas interpreter accepts — tiles not aligned to the hardware, more
+VMEM than a kernel may use.  The HLO must hold the
 Mosaic kernel (`tpu_custom_call`), not the interpreter's loops.  The
 MLP gate's table lookup, at granite_3_2b's chunk-step width, must
 compile to no gather for the TPU and keep its gather on the CPU.
@@ -22,6 +25,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.intmath import apply_lut
+from repro.kernels.moe_experts import moe_experts
 from repro.kernels.paged_attention import (
     TPU_PAGE_ALIGN,
     paged_attention_pallas,
@@ -65,14 +69,15 @@ def no_persistent_cache():
         compilation_cache.reset_cache()
 
 
-def _compile(one_chip, *, B, S, pps, n_pages, packed):
+def _compile(one_chip, *, B, S, pps, n_pages, packed, n_kv=K, hd=HD,
+             window=None):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    hd_store = HD // 2 if packed else HD
-    pool = sds((n_pages + 1, K, hd_store, PS), jnp.int8)
+    hd_store = hd // 2 if packed else hd
+    pool = sds((n_pages + 1, n_kv, hd_store, PS), jnp.int8)
     args = [
-        sds((B, H, S, HD), jnp.int8),
+        sds((B, H, S, hd), jnp.int8),
         pool,
         pool,
         sds((B, pps), jnp.int32),
@@ -85,7 +90,8 @@ def _compile(one_chip, *, B, S, pps, n_pages, packed):
     def step(q, k, v, table, pos, scale, *rq):
         kw = dict(k_rq=rq[0], v_rq=rq[1]) if packed else {}
         return paged_attention_pallas(
-            q, k, v, table, pos, score_scale=scale, group=H // K, **kw
+            q, k, v, table, pos, score_scale=scale, group=H // n_kv,
+            window=window, **kw
         )
 
     return jax.jit(step).lower(*args).compile()
@@ -112,6 +118,35 @@ def test_paged_kernel_compiles_for_v5e(
     mem = compiled.memory_analysis()
     pool_bytes = 2 * (n_pages + 1) * K * HD * PS // (2 if packed else 1)
     assert mem.argument_size_in_bytes >= pool_bytes
+
+
+@pytest.mark.parametrize("S", [1, 256], ids=["decode", "prefill_chunk"])
+def test_windowed_paged_kernel_compiles_for_v5e(
+    one_chip, no_persistent_cache, S
+):
+    compiled = _compile(one_chip, B=32, S=S, pps=32, n_pages=1024,
+                        packed=False, n_kv=4, hd=128, window=1024)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("T", [32, 32 * 256], ids=["decode", "chunk"])
+def test_moe_experts_compiles_for_v5e(one_chip, no_persistent_cache, T):
+    E, d, f, top_k = 64, 2304, 896, 8
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def rqt(*shape):
+        return dict({n: sds(shape) for n in ("m", "s0", "lo", "hi")},
+                    d=sds(()), zp=sds(()))
+
+    t = {"wg_q": sds((E, d, f), jnp.int8), "wu_q": sds((E, d, f), jnp.int8),
+         "wd_q": sds((E, f, d), jnp.int8), "g_rqt": rqt(E, f),
+         "u_rqt": rqt(E, f), "h_rqt": rqt(), "o_rqt": rqt(E, d),
+         "g_lut": sds((256,), jnp.int8), "zp_g": sds(())}
+    compiled = jax.jit(moe_experts).lower(
+        sds((T, d), jnp.int8), sds((T, top_k)), t).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 # 32 slots x a 128-wide chunk x granite_3_2b's d_ff: the SiLU gate's
