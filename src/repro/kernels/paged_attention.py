@@ -42,7 +42,8 @@ scope): the page table, the positions, the score scale and the int4
 requant operands live in SMEM (table and positions as scalar
 prefetch); the K/V pools stay in HBM (`memory_space=ANY`) and every
 page is copied into a double-buffered (2, hd, ps) VMEM buffer by an
-async DMA — page j + 1 is in flight while page j is used — so the
+async DMA — page j + 1 is in flight while page j is used, and only
+the pages lo .. hi of the row are copied (¶Page walk below) — so the
 arena's size is bounded by HBM, not VMEM.  Pools are position-minor,
 (n_pages + 1, K, hd, ps): a (ps, hd = 64) page would be padded to 128
 lanes in HBM and Mosaic refuses to copy it, while a (hd, ps) page is
@@ -68,13 +69,22 @@ Masking contract (serving.cache.PagedArena layout):
     in, garbage out, exactly like the gather path: the engine never
     reads logits of inactive rows.
 
+Page walk (`page_walk`): each of the two walks of a table row, scores
+then P.V, covers only its pages lo .. hi.  hi is the first page past
+the last query row's position pos[b] + S - 1, so no row of the slot
+sees a key on page hi or later; the pages from hi to the end of the
+row — PAGE_NULL trash and stale pages of a recycled slot alike — are
+neither copied nor multiplied: their logits lanes are filled with the
+mask value, so their probabilities are exactly 0, and the P.V walk
+skips them because their int8 probabilities are 0.  The float island
+still runs over the whole (S, T) row, so its max, sum and divide see
+the same lanes as the gather path and the output is unchanged.
+
 Sliding window (`window` W, static per call): the query at position i
-sees keys j with i - W < j <= i.  The page loop of a row starts at the
-page holding its first query's oldest visible position (pages before
-it are neither copied nor multiplied: their logits lanes are filled
-with the mask value, so their probabilities are exactly 0), and older
-positions inside the pages walked take the mask.  `window=None` builds
-the kernel without either.
+sees keys j with i - W < j <= i.  lo is the page holding the first
+query's oldest visible position; the pages before it are filled like
+the tail, and older positions inside the pages walked take the mask.
+Without a window lo is 0.
 
 GQA is folded into the page loads (kv head = h // group) — no
 head-expanded K/V copy exists anywhere.  `score_scale` may be a traced
@@ -124,6 +134,21 @@ def _unpack_planes(blk, rq_ref, kh):
     return planes
 
 
+def page_walk(pos, s_q: int, ps: int, pps: int, window=None, xp=jnp):
+    """The pages lo .. hi of a table row that the kernel copies, for a
+    slot whose S = `s_q` query rows start at position `pos`: lo is the
+    first page holding a position the first row sees (0 without a
+    window), hi the first page past every position the last row,
+    at pos + s_q - 1, sees.  Rows parked at INACTIVE_POS clamp to the
+    whole row.  `pos` may be a scalar or an array of slots; `xp` is
+    the array module (numpy for the engine's host-side count)."""
+    hi = xp.minimum((pos + (s_q - 1)) // ps + 1, pps)
+    if window is None:
+        return 0, hi
+    lo = xp.minimum(xp.maximum(pos - (window - 1), 0) // ps, pps - 1)
+    return lo, hi
+
+
 def _kernel(
     table_ref,
     pos_ref,
@@ -164,19 +189,17 @@ def _kernel(
             pool.at[table_ref[b, j], kh], buf.at[slot], sem.at[slot]
         )
 
-    # the first page that holds a position some query row sees
-    lo = 0 if window is None else jnp.minimum(
-        jnp.maximum(pos_b - (window - 1), 0) // ps, pps - 1)
+    lo, hi = page_walk(pos_b, s_q, ps, pps, window)
 
     def page_loop(pool, rq_ref, body, carry):
-        """body(j, planes, carry) over the table row's pages from `lo`,
-        page j + 1 copied while page j is used."""
+        """body(j, planes, carry) over the pages lo .. hi of the table
+        row, page j + 1 copied while page j is used."""
         copy(pool, lo, lo % 2).start()
 
         def step(j, carry):
             slot = j % 2
 
-            @pl.when(j + 1 < pps)
+            @pl.when(j + 1 < hi)
             def _():
                 copy(pool, j + 1, 1 - slot).start()
 
@@ -185,7 +208,7 @@ def _kernel(
             planes = _unpack_planes(blk, rq_ref, kh) if packed else [blk]
             return body(j, planes, carry)
 
-        return jax.lax.fori_loop(lo, pps, step, carry)
+        return jax.lax.fori_loop(lo, hi, step, carry)
 
     def lanes(j):
         return pl.ds(pl.multiple_of(j * ps, ps), ps)
@@ -213,13 +236,13 @@ def _kernel(
         logits_ref[:, lanes(j)] = lg + jnp.where(seen, 0.0, NEG_INF)
         return carry
 
-    if window is not None:
-        def unseen(j, carry):  # pages before the window: no copy, no dot
-            logits_ref[:, lanes(j)] = jnp.full((s_q, ps), NEG_INF,
-                                               jnp.float32)
-            return carry
+    def unseen(j, carry):  # pages outside the walk: no copy, no dot
+        logits_ref[:, lanes(j)] = jnp.full((s_q, ps), NEG_INF, jnp.float32)
+        return carry
 
+    if window is not None:
         jax.lax.fori_loop(0, lo, unseen, 0)
+    jax.lax.fori_loop(hi, pps, unseen, 0)
     page_loop(k_hbm, k_rq, score, 0)
 
     # ---- float island: the model's global probability image ----

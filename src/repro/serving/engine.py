@@ -118,7 +118,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.rep import Rep
-from repro.kernels.paged_attention import TPU_PAGE_ALIGN
+from repro.kernels.paged_attention import TPU_PAGE_ALIGN, page_walk
 from repro.layers.attention import INACTIVE_POS
 from repro.serving.cache import (
     Arena,
@@ -429,6 +429,8 @@ class ServingEngine:
         self._n_preempts = 0  # policy evictions executed
         self._t_first: Optional[float] = None
         self._t_last: Optional[float] = None
+        # the paged kernel's walk in this step's dispatch (telemetry)
+        self._walk: Dict[str, int] = {}
 
     # -- submission -----------------------------------------------------
     def submit(
@@ -798,7 +800,24 @@ class ServingEngine:
             n_prefilling=len(self.prefilling),
             admit_rejects=self._n_admit_rejects,
             **self.arena.gauges(),
+            **self._walk,
         )
+        self._walk = {}
+
+    def _record_walk(self, pos: np.ndarray, width: int):
+        """Telemetry gauges of the paged kernel's page walk in one
+        dispatch's full-attention layers, from the host positions the
+        dispatch carries: `paged_pages_walked` sums each row's pages
+        lo .. hi (`kernels.paged_attention.page_walk`), against
+        `paged_pages_table`, the whole B x pages-per-slot table."""
+        if not (self.tel.enabled and self.paged_kernel):
+            return
+        pps = self.arena.pages_per_slot
+        lo, hi = page_walk(pos, width, self.arena.page_size, pps, xp=np)
+        self._walk = {
+            "paged_pages_walked": int(np.sum(hi - lo)),
+            "paged_pages_table": pos.size * pps,
+        }
 
     def _dispatch_decode(self) -> _InFlightDecode:
         """Enqueue one fused decode over every active slot (async wrt
@@ -818,6 +837,7 @@ class ServingEngine:
                 # paged arena: allocate the page holding `pos` before
                 # the decode that writes there (no-op for SlotArena)
                 self.arena.touch(slot, st.pos)
+            self._record_walk(pos, 1)
             tel.dispatch("decode", (B,))
             with self._dispatch_ctx(), tel.annotate(
                 "repro.serving/decode"
@@ -1068,6 +1088,7 @@ class ServingEngine:
                         end=off + n,
                         pages=self.arena.span_pages(st.slot, off, off + n),
                     )
+            self._record_walk(start, W)
             tel.dispatch("unified", (B, W))
             with self._dispatch_ctx(), tel.annotate(
                 "repro.serving/unified"

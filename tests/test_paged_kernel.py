@@ -17,14 +17,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.requant import make_rqt
 from repro.kernels import ref
 from repro.kernels.paged_attention import (
+    page_walk,
     paged_attention_decode_pallas,
     paged_attention_pallas,
 )
 from repro.launch import variants
 from repro.launch.serve import deploy_model, serve_batch
-from repro.layers.attention import INACTIVE_POS, PAGE_NULL, _paged_kv_view
+from repro.layers.attention import (
+    INACTIVE_POS,
+    PAGE_NULL,
+    _kv4_operand,
+    _paged_kv_view,
+)
 from repro.serving import SchedulerConfig, ServingConfig, ServingEngine
 
 
@@ -57,13 +64,14 @@ def _rand_pools(rng, n_pages, K, ps, hd):
 
 
 def _gather_dense_acc_st(
-    q, k_pool, v_pool, table, pos, *, score_scale, group
+    q, k_pool, v_pool, table, pos, *, score_scale, group, window=None
 ):
     """The model's write-then-gather attention math for (S, T) query
     rows (the flagged oracle path of layers/attention.apply_id): dense
     logical view + global causal softmax + one global int8 probability
     image -> int32 P.V acc.  `pos` is each row's START position; query
-    row i sits at pos + i."""
+    row i sits at pos + i and, with a `window`, sees only its last
+    `window` positions."""
     kv = _paged_kv_view(k_pool, table)
     vv = _paged_kv_view(v_pool, table)
     kh = jnp.repeat(kv, group, axis=1)
@@ -73,7 +81,10 @@ def _gather_dense_acc_st(
     )
     S, T = q.shape[2], kh.shape[2]
     q_pos = pos[:, None, None, None] + jnp.arange(S)[None, None, :, None]
-    keep = jnp.arange(T)[None, None, None, :] <= q_pos
+    k_pos = jnp.arange(T)[None, None, None, :]
+    keep = k_pos <= q_pos
+    if window is not None:
+        keep = keep & (k_pos > q_pos - window)
     mask = jnp.where(keep, 0.0, -1e9).astype(jnp.float32)
     logits = scores.astype(jnp.float32) * jnp.float32(score_scale) + mask
     probs = jax.nn.softmax(logits, axis=-1)
@@ -180,6 +191,114 @@ def test_kernel_traced_scale_under_scan():
             q, kp, vp, table, pos, score_scale=float(sc), group=H // K
         )
         np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(want))
+
+
+# ---------------------------------------------------------------------
+# the page walk ends at each row's last visible page
+# ---------------------------------------------------------------------
+WALK_PS, WALK_PPS = 4, 5
+
+
+@pytest.mark.parametrize(
+    "pos,s_q,window,want",
+    [
+        # decode on the last slot of page 1, and on the first of page 2
+        (7, 1, None, (0, 2)),
+        (8, 1, None, (0, 3)),
+        # query rows 6..9 cross the edge of pages 1 and 2
+        (6, 4, None, (0, 3)),
+        # rows 9, 10 see positions 4..10 under a window of 6
+        (9, 2, 6, (1, 3)),
+        # the row's last slot, and parked rows: the whole row
+        (19, 1, None, (0, 5)),
+        (INACTIVE_POS, 1, None, (0, 5)),
+        (INACTIVE_POS, 4, 6, (4, 5)),
+    ],
+)
+def test_page_walk_bounds(pos, s_q, window, want):
+    """`page_walk`'s (lo, hi) for one slot in the kernel's scalar form
+    and in the engine's host form over a vector of slots."""
+    lo, hi = page_walk(jnp.int32(pos), s_q, WALK_PS, WALK_PPS, window)
+    assert (int(lo), int(hi)) == want
+    lo, hi = page_walk(np.asarray([pos, 0], np.int32), s_q, WALK_PS,
+                       WALK_PPS, window, xp=np)
+    assert (int(np.broadcast_to(lo, (2,))[0]), int(hi[0])) == want
+
+
+def _unpack_pool(pool, rq):
+    """An int4-packed pool (n, K, hd/2, ps) -> its int8 image
+    (n, K, hd, ps), page by page through the mirror's unpack."""
+    n, K = pool.shape[:2]
+    return jnp.stack([
+        jnp.stack([ref.kv4_unpack_page_ref(pool[p, kh].T, rq, kh).T
+                   for kh in range(K)])
+        for p in range(n)
+    ])
+
+
+@pytest.mark.parametrize(
+    "name,s_q,pos,window,kv_bits",
+    [
+        # short decode rows after a whole-row walk (row 0); their
+        # tails hold a stale real page of extreme K/V (all 127)
+        ("stale_tail", 1, [19, 2, 9, 6], None, 8),
+        # decode on the last slot of a page and on the first of the next
+        ("page_edges", 1, [19, 3, 4, 7], None, 8),
+        # query rows that cross a page edge
+        ("chunk_crosses_edge", 3, [17, 2, 6, 3], None, 8),
+        # a sliding window cuts the walk at both ends
+        ("window_both_ends", 2, [18, 9, 13, 5], 6, 8),
+        # int4-packed pools with unallocated (PAGE_NULL) tails
+        ("int4_null_tail", 1, [19, 5, 2, 10], None, 4),
+        # a parked row ahead of short rows
+        ("inactive_row", 2, [INACTIVE_POS, 5, 0, 11], None, 8),
+    ],
+)
+def test_kernel_exact_walk_ends(name, s_q, pos, window, kv_bits):
+    """Each row's walk stops at its last visible page (`page_walk`):
+    neither the pages of its table tail nor the logits a longer row
+    left in the kernel's scratch may reach its softmax.  Kernel ==
+    gather oracle, and == mirror where the mirror has the mask (no
+    window), at tolerance 0 on every row, parked rows included."""
+    rng = np.random.default_rng(61)
+    B, H, K, hd, ps, pps = 4, 4, 2, 8, WALK_PS, WALK_PPS
+    n_live = B * pps
+    hd_store = hd // 2 if kv_bits == 4 else hd
+    stale = n_live + 1  # a page another tenant wrote last
+    pools = []
+    for _ in range(2):
+        p = rng.integers(-127, 128, size=(n_live + 2, K, hd_store, ps))
+        p[stale] = 127
+        pools.append(jnp.asarray(p, jnp.int8))
+    kp, vp = pools
+    pos_v = np.asarray(pos, np.int32)
+    _, hi = page_walk(pos_v, s_q, ps, pps, window, xp=np)
+    table = np.where(np.arange(pps)[None, :] < hi[:, None],
+                     1 + rng.permutation(n_live).reshape(B, pps),
+                     PAGE_NULL if kv_bits == 4 else stale)
+    table[pos_v == INACTIVE_POS] = PAGE_NULL
+    table = jnp.asarray(table, jnp.int32)
+    q = jnp.asarray(
+        rng.integers(-127, 128, size=(B, H, s_q, hd)), jnp.int8
+    )
+    kw = dict(score_scale=4e-4, group=H // K)
+    if kv_bits == 4:
+        eps4 = rng.uniform(1.0, 20.0, size=K)
+        kw["k_rq"] = _kv4_operand(make_rqt(eps4, 1.0, acc_bound=8.0), K)
+        kw["v_rq"] = _kv4_operand(
+            make_rqt(np.roll(eps4, 1), 1.0, acc_bound=8.0), K)
+    pos_v = jnp.asarray(pos_v)
+    got = paged_attention_pallas(q, kp, vp, table, pos_v, window=window,
+                                 **kw)
+    if window is None:
+        mirror = ref.paged_attention_ref(q, kp, vp, table, pos_v, **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(mirror))
+    if kv_bits == 4:
+        kp = _unpack_pool(kp, kw.pop("k_rq"))
+        vp = _unpack_pool(vp, kw.pop("v_rq"))
+    oracle = _gather_dense_acc_st(q, kp, vp, table, pos_v, window=window,
+                                  **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(oracle))
 
 
 # ---------------------------------------------------------------------

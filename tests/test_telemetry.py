@@ -184,6 +184,58 @@ def test_step_records_phases_and_gauges(deployed):
 
 
 @pytest.mark.parametrize("prefill_chunk", [4, 0])
+@pytest.mark.parametrize("dispatch_depth", [0, 1])
+def test_paged_walk_gauges(deployed, dispatch_depth, prefill_chunk):
+    """`paged_pages_walked` of each step that dispatched is the sum
+    over its rows of the pages the paged kernel walks, the pages up to
+    the one holding the row's last query position (whole table rows
+    for parked rows), against `paged_pages_table` = B x pages per
+    slot; counted here from the positions each dispatch received.
+    Unified (chunked) and decode (whole-prompt) dispatches, sync and
+    async; the gather oracle records neither gauge."""
+    lm, tables = deployed
+    tel = Telemetry()
+    eng = make_engine(
+        lm, tables, n_slots=3, max_len=MAX_LEN, paged=True, page_size=8,
+        dispatch_depth=dispatch_depth, telemetry=tel,
+        scheduler=SchedulerConfig(max_prefills_per_step=2,
+                                  prefill_bucket=8,
+                                  prefill_chunk=prefill_chunk))
+    pps = eng.arena.pages_per_slot
+    want = []
+
+    def spy(fn):
+        def call(t, toks, caches, pos, *rest):
+            last = np.asarray(pos).astype(np.int64) + toks.shape[1] - 1
+            want.append((int(np.minimum(last // 8 + 1, pps).sum()),
+                         last.size * pps))
+            return fn(t, toks, caches, pos, *rest)
+        return call
+
+    eng._unified, eng._decode = spy(eng._unified), spy(eng._decode)
+    for prompt, g in _workload(lm.cfg.vocab):
+        eng.submit(prompt, max_new_tokens=g)
+        eng.step()
+    eng.run_until_drained()
+    got = [(s["paged_pages_walked"], s["paged_pages_table"])
+           for s in tel.steps if "paged_pages_walked" in s]
+    assert got == want
+    assert sum(w for w, _ in got) < sum(t for _, t in got)
+
+    gather_tel = Telemetry()
+    gather = make_engine(
+        lm, tables, n_slots=3, max_len=MAX_LEN, paged=True, page_size=8,
+        paged_kernel=False, dispatch_depth=dispatch_depth,
+        telemetry=gather_tel,
+        scheduler=SchedulerConfig(prefill_bucket=8,
+                                  prefill_chunk=prefill_chunk))
+    gather.submit(np.arange(1, 10), max_new_tokens=3)
+    gather.run_until_drained()
+    assert gather_tel.steps
+    assert not any("paged_pages_walked" in s for s in gather_tel.steps)
+
+
+@pytest.mark.parametrize("prefill_chunk", [4, 0])
 @pytest.mark.parametrize("paged", [False, True])
 @pytest.mark.parametrize("dispatch_depth", [0, 1])
 def test_advance_is_inside_harvest(deployed, paged, dispatch_depth,
