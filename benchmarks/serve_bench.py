@@ -162,7 +162,7 @@ def bench_engine(
     ttft_percentiles=False,
     itl_percentiles=False,
     repeats=1,
-    paged_kernel=None,
+    paged_kernel=True,
     mesh=None,
     kv_shard=False,
     dispatch_depth=0,

@@ -21,8 +21,9 @@ Pipeline (all int32):
   p_img    : (e * 2^Q) / sum(e)                  (one integer divide per row)
 
 Output: probability image in [0, 127] with quantum 1/127 — identical
-interface to the float-island path, so attention can swap islands per
-the `attn_softmax` variant.  Error vs float softmax <= ~1% (test).
+interface to the float-island path, so attention can swap islands
+(`QAttention.apply_id(..., int_softmax=True)`).  Error vs float
+softmax <= ~1% (test).
 """
 from __future__ import annotations
 

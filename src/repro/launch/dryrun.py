@@ -201,6 +201,23 @@ def roofline(
     }
 
 
+# --variant keys: lower_cell's keywords, with their types
+VARIANT_KEYS = {"microbatches": int, "zero2": bool, "weight_stationary": bool}
+
+
+def parse_variant(spec: str) -> dict:
+    """'microbatches=4,zero2=1' -> lower_cell keywords; unknown keys
+    raise KeyError."""
+    out = {}
+    for item in filter(None, spec.split(",")):
+        k, v = item.split("=")
+        if VARIANT_KEYS[k] is bool:
+            out[k] = v.lower() in ("1", "true", "yes")
+        else:
+            out[k] = int(v)
+    return out
+
+
 def run_cell(
     arch: str,
     shape: str,
@@ -208,8 +225,6 @@ def run_cell(
     out_dir: Path,
     variant: dict | None = None,
 ) -> dict:
-    from repro.launch import variants as var_mod
-
     cfg = get_config(arch)
     reason = cell_supported(cfg, shape)
     rec = {
@@ -226,8 +241,7 @@ def run_cell(
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multipod"))
     n_chips = int(np.prod(mesh.devices.shape))
     t0 = time.time()
-    with var_mod.use_variants(**(variant or {})):
-        lowered, lm = lower_cell(arch, shape, mesh)
+    lowered, lm = lower_cell(arch, shape, mesh, **(variant or {}))
     t1 = time.time()
     compiled = lowered.compile()
     t2 = time.time()
@@ -273,11 +287,12 @@ def main():
     ap.add_argument("--mesh", choices=["pod", "multipod"], default="pod")
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--out", default="results/dryrun")
-    ap.add_argument("--variant", default="", help="k=v,k=v overrides")
+    ap.add_argument("--variant", default="",
+                    help="k=v,k=v lower_cell overrides: "
+                         + ", ".join(VARIANT_KEYS))
     ap.add_argument("--tag", default="", help="suffix for the output json")
     args = ap.parse_args()
-    from repro.launch import variants as var_mod
-    variant = var_mod.parse(args.variant)
+    variant = parse_variant(args.variant)
 
     out_dir = Path(args.out)
     out_dir.mkdir(parents=True, exist_ok=True)

@@ -123,11 +123,11 @@ def train_state_specs(lm: DecoderLM):
     return trainable, opt
 
 
-def train_shardings(lm: DecoderLM, mesh, shape: str):
-    from repro.launch import variants as var_mod
-
+def train_shardings(lm: DecoderLM, mesh, shape: str, *, zero2=False):
+    """zero2: ZeRO-2 layout — params replicated across "data" (no
+    per-use weight all-gathers), Adam moments stay 2-D sharded; for
+    models whose params fit replicated."""
     trainable, opt = train_state_specs(lm)
-    zero2 = var_mod.get("train_zero2")
     tr_sh = params_sharding(trainable, mesh, weight_stationary=zero2)
     opt_sh = {
         "mu": params_sharding(opt["mu"], mesh),
@@ -179,15 +179,15 @@ def serve_input_specs(lm: DecoderLM, shape: str):
     return tables, tok, caches, pos
 
 
-def serve_shardings(lm: DecoderLM, mesh, shape: str):
-    from repro.launch import variants
-
+def serve_shardings(
+    lm: DecoderLM, mesh, shape: str, *, weight_stationary=False
+):
+    """weight_stationary: replicate the tables across the data axis
+    instead of 2-D FSDP sharding (no per-token weight all-gathers)."""
     s = SHAPES[shape]
     ins = serve_input_specs(lm, shape)
     tables = ins[0]
-    t_sh = params_sharding(
-        tables, mesh,
-        weight_stationary=variants.get("serve_weight_stationary"))
+    t_sh = params_sharding(tables, mesh, weight_stationary=weight_stationary)
     c_sh = caches_sharding(ins[2], mesh)
     x_sh = NamedSharding(
         mesh, batch_spec(mesh, len(ins[1].shape), shape=ins[1].shape))
@@ -222,37 +222,39 @@ MICROBATCH = {
 
 
 def lower_cell(
-    arch: str, shape: str, mesh, *, check=True, microbatches: int = 0
+    arch: str, shape: str, mesh, *, check=True, microbatches: int = 0,
+    zero2: bool = False, weight_stationary: bool = False,
 ):
-    """Lower one (arch x shape) cell on `mesh`. -> jax.stages.Lowered."""
+    """Lower one (arch x shape) cell on `mesh`. -> jax.stages.Lowered.
+
+    microbatches: gradient-accumulation override (0: the MICROBATCH
+    table); zero2 / weight_stationary: see train_shardings /
+    serve_shardings."""
     cfg = get_config(arch)
     reason = cell_supported(cfg, shape)
     if reason and check:
         raise ValueError(reason)
-    from repro.launch import variants as var_mod
-
     s = SHAPES[shape]
-    mb = (
-        microbatches
-        or var_mod.get("microbatches")
-        or MICROBATCH.get((arch, shape), 1)
-    )
+    mb = microbatches or MICROBATCH.get((arch, shape), 1)
     lm = DecoderLM(cfg, max_seq=s["seq"] + (1 if s["kind"] == "train" else 0))
     with mesh, use_profile(mesh):
         if s["kind"] == "train":
-            in_sh, out_sh, in_specs = train_shardings(lm, mesh, shape)
+            in_sh, out_sh, in_specs = train_shardings(
+                lm, mesh, shape, zero2=zero2)
             step = build_train_step(lm, microbatches=mb)
             lowered = jax.jit(
                 step, in_shardings=in_sh, out_shardings=out_sh,
             ).lower(*in_specs)
         elif s["kind"] == "prefill":
-            in_sh, out_sh, ins = serve_shardings(lm, mesh, shape)
+            in_sh, out_sh, ins = serve_shardings(
+                lm, mesh, shape, weight_stationary=weight_stationary)
             step = build_prefill_step(lm)
             lowered = jax.jit(
                 step, in_shardings=in_sh, out_shardings=out_sh,
             ).lower(*ins)
         else:
-            in_sh, out_sh, ins = serve_shardings(lm, mesh, shape)
+            in_sh, out_sh, ins = serve_shardings(
+                lm, mesh, shape, weight_stationary=weight_stationary)
             step = build_decode_step(lm)
             lowered = jax.jit(
                 step, in_shardings=in_sh, out_shardings=out_sh,

@@ -55,7 +55,7 @@ ID attention — single-token decode AND multi-token chunked-prefill —
 then runs the
 fused paged-attention kernel straight over the pools
 (kernels/paged_attention.py — bit-exact with the unfused math, see
-its module doc) unless `variants paged_decode="gather"` selects the
+its module doc) unless `apply_id(..., paged_kernel=False)` selects the
 oracle path, which gathers the logical (B, K, T, hd) view back
 through the table.  Positions past each query row's position (stale
 pages, the PAGE_NULL trash page, the unwritten suffix of a chunk)
@@ -272,7 +272,7 @@ class QAttention:
         if self.yarn is not None:
             eps_s = eps_s * self.yarn.attention_factor ** 2
         t["score_scale"] = np.float32(eps_s)
-        # integer-softmax tables (attn_softmax=int variant; all-int32)
+        # integer-softmax tables (apply_id int_softmax=True; all-int32)
         from repro.core.intsoftmax import make_int_softmax_tables
 
         t["sm_tabs"] = make_int_softmax_tables(float(eps_s))
@@ -333,8 +333,14 @@ class QAttention:
     # -- integer path -------------------------------------------------------
     BLOCKWISE_THRESHOLD = 4096  # S_q above this -> streaming attention
 
-    def apply_id(self, t, s_x, *, cache=None, pos=None):
-        """s_x: (B, S, d) int8 (zp=0).  Returns (int32 wo-acc, cache)."""
+    def apply_id(self, t, s_x, *, cache=None, pos=None, paged_kernel=True,
+                 int_softmax=False):
+        """s_x: (B, S, d) int8 (zp=0).  Returns (int32 wo-acc, cache).
+
+        paged_kernel: over a paged cache, attend through the fused
+        paged kernel (False: the write-then-gather oracle).
+        int_softmax: integer-only softmax island (core/intsoftmax.py)
+        instead of the float one; it runs on the gather path."""
         from repro.sharding.hints import hint
 
         subs = self._sub()
@@ -356,20 +362,17 @@ class QAttention:
 
         if cache is not None:
             if "table" in cache:
-                from repro.launch import variants
-
                 # int4-packed pools (DESIGN.md §Serving ¶Sub-8-bit
                 # KV): a pool whose trailing axis is hd/2 stores two
                 # nibbles per cell — thread the per-head pack/unpack
                 # requant images through the write and the read
                 kv4 = t["kv4"] if cache["k"].shape[-2] != hd else None
-                if (variants.get("paged_decode") == "kernel"
-                        and variants.get("attn_softmax") != "int"):
+                if paged_kernel and not int_softmax:
                     # fused paged attention (S == 1 decode, S > 1
                     # chunked prefill): no dense logical KV view —
                     # the kernel streams K/V page by page through the
                     # table (the gather path below stays available as
-                    # the parity oracle via paged_decode="gather")
+                    # the parity oracle via paged_kernel=False)
                     return self._paged_kernel_attend(
                         t, q, k, v, cache, pos, subs, kv4=kv4
                     )
@@ -395,8 +398,6 @@ class QAttention:
         if S > self.BLOCKWISE_THRESHOLD:
             s_ctx = self._blockwise_id(t, q, kh, vh, pos)
         else:
-            from repro.launch import variants
-
             scores = hint(
                 jnp.einsum(
                     "bhsd,bhtd->bhst", q, kh,
@@ -404,13 +405,13 @@ class QAttention:
                 ),
                 "probs_dec" if S == 1 else "probs",
             )
-            if variants.get("attn_softmax") == "int" and "sm_tabs" in t:
+            if int_softmax and "sm_tabs" in t:
                 # integer-only softmax: NO float island at all
-                from repro.core.intsoftmax import int_softmax
+                from repro.core import intsoftmax
 
                 bmask = _bool_mask(S, T, pos, self.window)
                 s_p = hint(
-                    int_softmax(scores, t["sm_tabs"], mask=bmask),
+                    intsoftmax.int_softmax(scores, t["sm_tabs"], mask=bmask),
                     "probs_dec" if S == 1 else "probs",
                 )
             else:
@@ -718,8 +719,6 @@ def _cache_write(cache, new, pos):
     multi-token chunks (chunked prefill) — dynamic_update_slice has no
     per-row offsets.  Rows parked at INACTIVE_POS (>= T) write nothing.
     """
-    from repro.launch import variants
-
     S, T = new.shape[2], cache.shape[2]
     pos_v = None if pos is None else jnp.asarray(pos)
     if pos_v is not None and pos_v.ndim == 1:
@@ -739,7 +738,7 @@ def _cache_write(cache, new, pos):
         return jnp.where(valid[:, None, :, None], gathered, cache)
     if S == T:
         return new
-    if S == 1 and variants.get("kv_update") == "onehot":
+    if S == 1:
         oh = (jnp.arange(T) == pos).astype(cache.dtype)[None, None, :, None]
         return cache * (1 - oh) + new.astype(cache.dtype) * oh
     return jax.lax.dynamic_update_slice_in_dim(cache, new, pos, axis=2)

@@ -32,19 +32,6 @@ from repro.layers.linear import QLinear
 CHUNK = 128
 
 
-def _island_dtype():
-    from repro.launch import variants
-
-    return (jnp.bfloat16 if variants.get("ssm_island_dtype") == "bf16"
-            else jnp.float32)
-
-
-def _chunk_len():
-    from repro.launch import variants
-
-    return variants.get("ssm_chunk") or CHUNK
-
-
 def _assoc_scan(a, u, h0=None):
     """h_t = a_t * h_{t-1} + u_t along axis 1 (time). a/u broadcastable."""
     if h0 is not None:
@@ -92,24 +79,22 @@ def _chunked_recurrence(inputs, make_au, y_of_h, h_shape, h0=None,
     Returns (y (B, L, ...), h_last).  The chunk body is rematerialized
     (jax.checkpoint), so backward keeps only chunk inputs + carries.
     """
-    chunk = _chunk_len()
-    dt_isl = _island_dtype()
     L = jax.tree.leaves(inputs)[0].shape[1]
-    n = max(1, L // chunk)
-    if L % chunk != 0 or L < chunk:
+    n = max(1, L // CHUNK)
+    if L % CHUNK != 0 or L < CHUNK:
         a, u = make_au(inputs)
         h = _assoc_scan(a, u, h0=h0)
         return y_of_h(h, inputs), h[:, -1]
     chunked = jax.tree.map(
-        lambda t: t.reshape(t.shape[0], n, chunk, *t.shape[2:]
+        lambda t: t.reshape(t.shape[0], n, CHUNK, *t.shape[2:]
                             ).swapaxes(0, 1), inputs)
 
     from repro.sharding.hints import hint
 
     def step(h_prev, xs):
         a, u = make_au(xs)
-        h = _assoc_scan(a.astype(dt_isl), u.astype(dt_isl),
-                        h0=h_prev.astype(dt_isl))
+        h = _assoc_scan(a.astype(jnp.float32), u.astype(jnp.float32),
+                        h0=h_prev.astype(jnp.float32))
         # carry in f32 (decay products compound across 256+ chunks),
         # channel-sharded on the model axis (replicated carries force
         # per-chunk data-axis gathers)

@@ -210,9 +210,11 @@ class DenseBlock:
         return t, eps_r2
 
     # -- integer --------------------------------------------------------------
-    def apply_id(self, t, s_x, *, cache=None, pos=None, valid=None):
+    def apply_id(self, t, s_x, *, cache=None, pos=None, valid=None,
+                 paged_kernel=True):
         """`valid` (B, S) bool marks the real tokens; the MoE routes the
-        others nowhere (None: every token is real)."""
+        others nowhere (None: every token is real).  `paged_kernel`
+        goes to the attention (QAttention.apply_id)."""
         from repro.core.requant import apply_rqt
         from repro.sharding.hints import hint
 
@@ -220,7 +222,7 @@ class DenseBlock:
         s_x = hint(s_x, "act_bs_only" if self.n_experts > 0 else "act_bsd")
         h = subs["norm1"].apply_id(t["norm1"], s_x)
         a_acc, cache = subs["attn"].apply_id(
-            t["attn"], h, cache=cache, pos=pos
+            t["attn"], h, cache=cache, pos=pos, paged_kernel=paged_kernel
         )
         s_r = subs["add1"].apply_id(t["add1"], s_x, a_acc)
         h = subs["norm2"].apply_id(t["norm2"], s_r)
@@ -424,7 +426,8 @@ class SharedAttnBlock:
         t["add"] = tadd
         return t, eps_out
 
-    def apply_id(self, t, s_x, s_x0, *, cache=None, pos=None):
+    def apply_id(self, t, s_x, s_x0, *, cache=None, pos=None,
+                 paged_kernel=True):
         from repro.core.requant import apply_rqt
 
         subs = self._subs()
@@ -432,7 +435,8 @@ class SharedAttnBlock:
         b_ = apply_rqt(s_x0.astype(jnp.int32), t["cat_rqt_x0"])
         cat = jnp.concatenate([a_, b_], axis=-1)
         h = subs["norm"].apply_id(t["norm"], cat)
-        acc, cache = subs["attn"].apply_id(t["attn"], h, cache=cache, pos=pos)
+        acc, cache = subs["attn"].apply_id(t["attn"], h, cache=cache, pos=pos,
+                                           paged_kernel=paged_kernel)
         s_out = subs["add"].apply_id(t["add"], s_x, acc)
         return s_out, cache
 

@@ -204,12 +204,14 @@ class DecoderLM:
 
     def apply(
         self, p, x, rep, *, qstate=None, caches=None, pos=None, calib=None,
-        valid=None,
+        valid=None, paged_kernel=True,
     ):
         """x: embedded input (B,S,d) float. -> (hidden, caches, aux_sum)
 
         valid (B, S) bool (ID only): the real tokens of a serving
-        dispatch; MoE layers route the others nowhere."""
+        dispatch; MoE layers route the others nowhere.
+        paged_kernel (ID only): attention over paged caches runs the
+        fused paged kernel; False runs the write-then-gather oracle."""
         c = self.cfg
         aux_total = jnp.float32(0.0)
         new_caches = []
@@ -231,7 +233,8 @@ class DecoderLM:
             else:
                 x, caches_i, aux = self._seg_scan(
                     kind, tpl, seg_p, seg_qs, x, x0, rep,
-                    caches[ci] if caches else None, pos, p, valid)
+                    caches[ci] if caches else None, pos, p, valid,
+                    paged_kernel)
             aux_total = aux_total + aux
             new_caches.append(caches_i)
             ci += 1
@@ -313,7 +316,7 @@ class DecoderLM:
 
     def _seg_scan(
         self, kind, tpl, seg_p, seg_qs, x, x0, rep, caches, pos, p_root,
-        valid=None,
+        valid=None, paged_kernel=True,
     ):
         """lax.scan over stacked layer params (jit path)."""
         c = self.cfg
@@ -324,7 +327,8 @@ class DecoderLM:
                 h, aux = carry
                 lp, lqs, lc = xs
                 if rep is Rep.ID:
-                    kw = {"valid": valid} if kind == "dense" else {}
+                    kw = ({"valid": valid, "paged_kernel": paged_kernel}
+                          if kind == "dense" else {})
                     h2, lc2 = tpl.apply_id(lp, h, cache=lc, pos=pos, **kw)
                     a2 = aux
                 else:
@@ -354,9 +358,11 @@ class DecoderLM:
                 cb = _tree_slice(lc, 1) if lc is not None else None
                 if rep is Rep.ID:
                     h, ca2 = a_tpl.apply_id(lp["a"], h, cache=ca, pos=pos,
-                                            valid=valid)
+                                            valid=valid,
+                                            paged_kernel=paged_kernel)
                     h, cb2 = b_tpl.apply_id(lp["b"], h, cache=cb, pos=pos,
-                                            valid=valid)
+                                            valid=valid,
+                                            paged_kernel=paged_kernel)
                     a_sum = aux
                 else:
                     h, ca2, _ = a_tpl.apply_float(
@@ -403,7 +409,8 @@ class DecoderLM:
                 sc_in = lc["sh"] if lc is not None else None
                 if rep is Rep.ID:
                     h, sc_out = sha_tpl.apply_id(
-                        lp["sh"], h, x0, cache=sc_in, pos=pos
+                        lp["sh"], h, x0, cache=sc_in, pos=pos,
+                        paged_kernel=paged_kernel,
                     )
                 else:
                     h, sc_out, _ = sha_tpl.apply_float(
@@ -581,7 +588,8 @@ class DecoderLM:
             h = x[:, -1:, :] if last_only else x
         return self.logits_id(t, h), caches
 
-    def prefill_chunk(self, t, batch, caches, start_pos, last_index):
+    def prefill_chunk(self, t, batch, caches, start_pos, last_index, *,
+                      paged_kernel=True):
         """ID batched + chunked prefill over a shared cache arena.
 
         batch (B, C) int32: one C-token prompt chunk per arena row, for
@@ -600,7 +608,7 @@ class DecoderLM:
         the final prompt token *within the chunk* — before the vocab
         projection, so no (B, C, V) logits are materialized.  Only rows
         whose final chunk just completed have meaningful logits; the
-        engine ignores the rest.
+        engine ignores the rest.  paged_kernel: as in `apply`.
         """
         x = self.embed_in_id(t, batch)
         # real tokens: a row's columns up to its last_index, unless the
@@ -608,13 +616,13 @@ class DecoderLM:
         valid = ((start_pos < INACTIVE_POS)[:, None]
                  & (jnp.arange(batch.shape[1]) <= last_index[:, None]))
         x, caches, _ = self.apply(t, x, Rep.ID, caches=caches, pos=start_pos,
-                                  valid=valid)
+                                  valid=valid, paged_kernel=paged_kernel)
         idx = jnp.broadcast_to(
             last_index[:, None, None], (x.shape[0], 1, x.shape[-1]))
         h = jnp.take_along_axis(x, idx, axis=1)
         return self.logits_id(t, h), caches
 
-    def decode_step(self, t, token, caches, pos):
+    def decode_step(self, t, token, caches, pos, *, paged_kernel=True):
         """ID single-token decode. token (B,1) -> int32 logits (B,1,V).
 
         pos: scalar (lockstep batch) or per-slot vector (B,) — the
@@ -625,10 +633,12 @@ class DecoderLM:
         per-slot page "table" and its KV leaves are page pools
         (serving.cache.PagedArena.decode_view) — the table is scanned
         alongside the layer-stacked leaves, so paging needs no change
-        to this step function or its single compilation.
+        to this step function or its single compilation.  paged_kernel:
+        as in `apply`.
         """
         x = self.embed_in_id(t, token)
-        x, caches, _ = self.apply(t, x, Rep.ID, caches=caches, pos=pos)
+        x, caches, _ = self.apply(t, x, Rep.ID, caches=caches, pos=pos,
+                                  paged_kernel=paged_kernel)
         return self.logits_id(t, x), caches
 
     def init_caches(self, B: int, max_len: int, rep: Rep, dtype=None):

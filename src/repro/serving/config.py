@@ -4,9 +4,7 @@
 variant, mesh placement, dispatch pipelining, telemetry...).  This
 dataclass collapses the sprawl into a single value the engine — and
 `cache.make_arena` — consume, with validation at construction instead
-of failure inside the first step.  The old keywords still work through
-a deprecation shim (`ServingConfig.from_legacy`), so call sites can
-migrate incrementally; in-repo callers all pass a config.
+of failure inside the first step.
 
 The `policy` field is the scheduling brain (serving/policy.py,
 DESIGN.md §Scheduling): None means `FCFSPolicy()`, today's behavior.
@@ -22,22 +20,6 @@ from repro.serving.scheduler import SchedulerConfig
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from repro.serving.policy import SchedulingPolicy
 
-# ServingEngine keywords accepted before the config existed, in the
-# pre-config signature order (the from_legacy contract).
-LEGACY_KWARGS = (
-    "n_slots",
-    "max_len",
-    "scheduler",
-    "paged",
-    "page_size",
-    "n_pages",
-    "paged_kernel",
-    "mesh",
-    "kv_shard",
-    "dispatch_depth",
-    "telemetry",
-)
-
 
 @dataclasses.dataclass
 class ServingConfig:
@@ -51,8 +33,9 @@ class ServingConfig:
     # a multiple of 128 on a TPU (kernels/paged_attention.TPU_PAGE_ALIGN)
     page_size: int = 128
     n_pages: Optional[int] = None  # None: SlotArena-equivalent positions
-    # paged decode variant: None -> the fused kernel iff paged
-    paged_kernel: Optional[bool] = None
+    # paged attention: the fused kernel (False: the write-then-gather
+    # oracle); ignored without the paged arena
+    paged_kernel: bool = True
     # multi-device placement (DESIGN.md §Serving ¶Multi-device)
     mesh: Any = None
     kv_shard: bool = False
@@ -127,15 +110,3 @@ class ServingConfig:
             )
         if self.scheduler is None:
             self.scheduler = SchedulerConfig()
-
-    @classmethod
-    def from_legacy(cls, **kwargs) -> "ServingConfig":
-        """Map the pre-config ServingEngine keywords onto a config
-        (the deprecation shim's translation table)."""
-        unknown = sorted(set(kwargs) - set(LEGACY_KWARGS))
-        if unknown:
-            raise TypeError(
-                f"unknown ServingEngine keyword(s): {unknown} "
-                f"(legacy keywords: {list(LEGACY_KWARGS)})"
-            )
-        return cls(**kwargs)

@@ -110,7 +110,6 @@ import collections
 import contextlib
 import dataclasses
 import time
-import warnings
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import jax
@@ -217,24 +216,7 @@ class ServingEngine:
         config: Optional[ServingConfig] = None,
         *,
         on_token: Optional[Callable[[int, int], None]] = None,
-        **legacy,
     ):
-        if legacy:
-            # deprecation shim: the pre-config keyword signature
-            # (n_slots=..., paged=..., ...) still works, translated
-            # through ServingConfig.from_legacy
-            if config is not None:
-                raise TypeError(
-                    "pass either a ServingConfig or legacy keywords, "
-                    f"not both (got {sorted(legacy)})"
-                )
-            warnings.warn(
-                "ServingEngine(**kwargs) is deprecated; pass "
-                "ServingEngine(lm, tables, ServingConfig(...))",
-                DeprecationWarning,
-                stacklevel=2,
-            )
-            config = ServingConfig.from_legacy(**legacy)
         cfg = self.config = config if config is not None else ServingConfig()
         if lm.cfg.input_mode != "tokens":
             raise ValueError(
@@ -274,13 +256,10 @@ class ServingEngine:
         # paged attention path: the fused paged-attention kernel by
         # default (kernels/paged_attention.py — K/V stream page by page
         # through the table, no dense logical gather), or the
-        # write-then-gather jnp oracle when paged_kernel=False.  The
-        # variant is pinned at trace time, so each compiled dispatch
-        # bakes the chosen path in — for BOTH the decode and the
-        # unified (S-wide) dispatch.
-        self.paged_kernel = cfg.paged if cfg.paged_kernel is None else (
-            bool(cfg.paged_kernel) and cfg.paged
-        )
+        # write-then-gather jnp oracle when paged_kernel=False.  Both
+        # the decode and the unified (S-wide) dispatch pass it to the
+        # model, so each compiled dispatch bakes the chosen path in.
+        self.paged_kernel = cfg.paged and cfg.paged_kernel
         device = (
             mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
         )
@@ -309,11 +288,9 @@ class ServingEngine:
         self._next_id = 0
 
         def _decode_step(t, token, caches, pos):
-            from repro.launch import variants
-
-            mode = "kernel" if self.paged_kernel else "gather"
-            with variants.use_variants(paged_decode=mode):
-                logits, new_caches = lm.decode_step(t, token, caches, pos)
+            logits, new_caches = lm.decode_step(
+                t, token, caches, pos, paged_kernel=self.paged_kernel
+            )
             # greedy argmax stays on-device: the async dispatch queue
             # harvests a (B,) token vector, never (B, 1, V) logits
             return jnp.argmax(logits[:, 0, :], axis=-1), new_caches
@@ -327,13 +304,9 @@ class ServingEngine:
             # attention kernel): lm.prefill_chunk over every arena row
             # at once — decode rows are width-1 chunks (last_index 0),
             # so one kernel call serves the mixed prefill+decode batch.
-            from repro.launch import variants
-
-            mode = "kernel" if self.paged_kernel else "gather"
-            with variants.use_variants(paged_decode=mode):
-                logits, new_caches = lm.prefill_chunk(
-                    t, toks, caches, start, last
-                )
+            logits, new_caches = lm.prefill_chunk(
+                t, toks, caches, start, last, paged_kernel=self.paged_kernel
+            )
             return jnp.argmax(logits[:, 0, :], axis=-1), new_caches
 
         if mesh is None:

@@ -34,7 +34,7 @@ shares one dispatch instead of queueing B=1 prefills.  The packing
 policy lives in `plan_chunks`: FIFO by admission order, one chunk per
 request per step (chunks of one request are sequential by definition).
 
-Async dispatch (`ServingEngine(dispatch_depth=1)`, DESIGN.md §Serving
+Async dispatch (`ServingConfig(dispatch_depth=1)`, DESIGN.md §Serving
 ¶Multi-device): every decision this module makes — `pop_if` admission,
 `plan_chunks` packing — reads host-side state only (queue order, arena
 counters, chunk cursors), never a device value.  That is what lets the

@@ -1,5 +1,5 @@
 """Integer-only softmax (core/intsoftmax.py): accuracy vs float oracle +
-the attention island swap (attn_softmax=int leaves NO float ops)."""
+the attention island swap (int_softmax=True leaves NO float ops)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -53,10 +53,9 @@ def test_int_softmax_is_integer_only():
 
 
 def test_attention_island_swap():
-    """attn_softmax=int: ID attention runs with ZERO float ops."""
+    """int_softmax=True: ID attention runs with ZERO float ops."""
     from repro.core.calibrate import Calibrator
     from repro.core.rep import Rep
-    from repro.launch.variants import use_variants
     from repro.layers.attention import QAttention
 
     attn = QAttention(d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
@@ -72,10 +71,9 @@ def test_attention_island_swap():
     t_j = jax.tree.map(jnp.asarray, t)
     s_x = jnp.asarray(np.clip(np.floor(np.asarray(x) / (2 * 4.0 / 255)),
                               -128, 127), jnp.int8)
-    with use_variants(attn_softmax="int"):
-        acc_int, _ = attn.apply_id(t_j, s_x)
-        jaxpr = jax.make_jaxpr(
-            lambda s: attn.apply_id(t_j, s)[0])(s_x)
+    acc_int, _ = attn.apply_id(t_j, s_x, int_softmax=True)
+    jaxpr = jax.make_jaxpr(
+        lambda s: attn.apply_id(t_j, s, int_softmax=True)[0])(s_x)
     # no float-typed outputs anywhere in the attention jaxpr
     bad = [e.primitive.name for e in jaxpr.jaxpr.eqns
            if any(jnp.issubdtype(ov.aval.dtype, jnp.floating)

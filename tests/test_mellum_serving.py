@@ -38,7 +38,7 @@ def deployed():
 def _windowed_oracle(q, k_pool, v_pool, table, pos, *, score_scale, group,
                      window):
     """The gather path's math (layers/attention.apply_id with
-    paged_decode="gather") with the window in its mask."""
+    paged_kernel=False) with the window in its mask."""
     kh = jnp.repeat(_paged_kv_view(k_pool, table), group, axis=1)
     vh = jnp.repeat(_paged_kv_view(v_pool, table), group, axis=1)
     scores = jnp.einsum("bhsd,bhtd->bhst", q, kh,

@@ -24,7 +24,6 @@ from repro.kernels.paged_attention import (
     paged_attention_decode_pallas,
     paged_attention_pallas,
 )
-from repro.launch import variants
 from repro.launch.serve import deploy_model, serve_batch
 from repro.layers.attention import (
     INACTIVE_POS,
@@ -36,9 +35,7 @@ from repro.serving import SchedulerConfig, ServingConfig, ServingEngine
 
 
 def make_engine(lm, tables, **kw):
-    """Every test engine goes through the typed ServingConfig surface
-    (the legacy kwarg shim has its own dedicated tests in
-    tests/test_policy.py)."""
+    """Every test engine goes through the typed ServingConfig surface."""
     on_token = kw.pop("on_token", None)
     return ServingEngine(
         lm, tables, ServingConfig(**kw), on_token=on_token)
@@ -382,7 +379,7 @@ def test_kernel_exact_mixed_ragged_batch():
 # ---------------------------------------------------------------------
 # engine-level: kernel == gather oracle == SlotArena, token for token
 # ---------------------------------------------------------------------
-def _run(lm, tables, specs, prompts, *, paged, paged_kernel=None,
+def _run(lm, tables, specs, prompts, *, paged, paged_kernel=True,
          page_size=8, n_slots=3, max_len=MAX_LEN):
     eng = make_engine(
         lm, tables, n_slots=n_slots, max_len=max_len, paged=paged,

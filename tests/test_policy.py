@@ -15,7 +15,7 @@ Acceptance pinned here:
     eviction with rollback, and SLO aging (order only) — checked
     against hand-built EngineViews, no model needed.
   - The Arena protocol + make_arena factory and the ServingConfig
-    surface (validation, legacy-kwarg deprecation shim) behave.
+    surface (validation) behave.
   - preempt/resume trace events validate through tools/trace_summary
     (ordering state machine), and malformed sequences are rejected.
 """
@@ -385,7 +385,7 @@ def test_arena_protocol_and_factory(deployed):
 
 
 # ---------------------------------------------------------------------
-# ServingConfig + deprecation shim (satellite 1)
+# ServingConfig validation
 # ---------------------------------------------------------------------
 def test_serving_config_validation():
     with pytest.raises(ValueError):
@@ -401,31 +401,6 @@ def test_serving_config_validation():
     with pytest.raises(ValueError):
         ServingConfig(kv_shard=True)  # needs a mesh
     assert isinstance(ServingConfig().scheduler, SchedulerConfig)
-    with pytest.raises(TypeError):
-        ServingConfig.from_legacy(slots=4)  # unknown keyword
-
-
-def test_legacy_kwargs_shim(deployed):
-    """The pre-config keyword signature still works — warning once,
-    serving identically — and mixing both surfaces is an error."""
-    lm, tables = deployed
-    rng = np.random.default_rng(23)
-    prompt = rng.integers(0, lm.cfg.vocab, size=(6,))
-    with pytest.warns(DeprecationWarning):
-        legacy = ServingEngine(
-            lm, tables, n_slots=1, max_len=16,
-            scheduler=SchedulerConfig(prefill_bucket=8))
-    legacy.submit(prompt, max_new_tokens=6)
-    (a,) = legacy.run_until_drained()
-    cfg = ServingConfig(
-        n_slots=1, max_len=16,
-        scheduler=SchedulerConfig(prefill_bucket=8))
-    modern = ServingEngine(lm, tables, cfg)
-    modern.submit(prompt, max_new_tokens=6)
-    (b,) = modern.run_until_drained()
-    assert a.tokens == b.tokens
-    with pytest.raises(TypeError):
-        ServingEngine(lm, tables, cfg, n_slots=1)
 
 
 # ---------------------------------------------------------------------

@@ -27,9 +27,7 @@ from repro.serving.telemetry import EVENT_FIELDS, PHASES
 
 
 def make_engine(lm, tables, **kw):
-    """Every test engine goes through the typed ServingConfig surface
-    (the legacy kwarg shim has its own dedicated tests in
-    tests/test_policy.py)."""
+    """Every test engine goes through the typed ServingConfig surface."""
     on_token = kw.pop("on_token", None)
     return ServingEngine(
         lm, tables, ServingConfig(**kw), on_token=on_token)
